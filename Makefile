@@ -4,7 +4,7 @@
 # offline CI container without an install step.
 #
 # CI (.github/workflows/ci.yml) runs: test-fast + bench-smoke + check-bench
-# on a Python 3.10/3.11 matrix (test-fast includes the golden-corpus format
+# on Python 3.12 (test-fast includes the golden-corpus format
 # pin, tests/test_golden.py), test-multidevice + bench-sharded-smoke in a
 # separate multidevice lane (8 forced host devices), test-serving +
 # bench-kv-smoke in a serving lane (also 8 forced host devices, for the
@@ -28,7 +28,7 @@ test:
 
 # test_properties.py is excluded here: its strategies deliberately mint
 # fresh jit traces per fuzzed geometry, which is the dedicated property
-# lane's job (test-property below) — running it in the 2x-Python CI matrix
+# lane's job (test-property below) — running it in the CI test lane
 # would duplicate that wall-clock on every PR.  Likewise the stress-marked
 # concurrency tests belong to the async lane (test-async below).  Plain
 # `make test` still includes both.

@@ -1,4 +1,4 @@
-"""Roofline analysis for the GPULZ Pallas kernel itself (TPU v5e model).
+"""Roofline analysis for the GPULZ Pallas kernel itself (per TPU kind).
 
 The matching kernel is VPU (vector-unit) work — equality compares and integer
 doubling recurrences, no MXU formulation exists (DESIGN.md §2).  Terms:
@@ -20,9 +20,12 @@ from __future__ import annotations
 
 
 from benchmarks.common import emit
+from repro.launch.roofline import DRYRUN_DEVICE_KIND, device_peaks
 
-VPU_OPS = 3.85e12      # int ops/s/chip (8x128 lanes x 4 ALUs x 0.94 GHz)
-HBM_BW = 819e9
+# Vector-unit integer throughput per chip, keyed like DEVICE_PEAKS.  Not a
+# published figure: derived as 8x128 lanes x 4 ALUs x clock (0.94 GHz on
+# v5e).
+VPU_OPS = {"TPU v5 lite": 3.85e12}
 
 
 def levels_for(window: int) -> int:
@@ -38,16 +41,18 @@ def kernel_ops_per_symbol(window: int) -> float:
     return window * (2 + 4 * levels_for(window) + 5)
 
 
-def analytic(run_xla_comparison: bool = True):
-    print("# kernel_roofline: name,us_per_call,derived")
+def analytic(run_xla_comparison: bool = True, device_kind=DRYRUN_DEVICE_KIND):
+    hbm_bw = device_peaks(device_kind).hbm_bw  # raises for an unknown kind
+    vpu_ops = VPU_OPS[device_kind]
+    print(f"# kernel_roofline ({device_kind}): name,us_per_call,derived")
     for w in (32, 64, 128, 255):
         ops = kernel_ops_per_symbol(w)
-        sym_s = VPU_OPS / ops
+        sym_s = vpu_ops / ops
         for s in (1, 2, 4):
             gbs = sym_s * s / 1e9
             emit(f"kernel/analytic/W{w}/S{s}", 0.0,
                  f"{gbs:.2f}GB/s-compute-bound")
-        mem_bound = HBM_BW / 12 / 1e9
+        mem_bound = hbm_bw / 12 / 1e9
         emit(f"kernel/analytic/W{w}/mem-bound", 0.0,
              f"{mem_bound:.1f}Gsym/s (not binding: {sym_s/1e9:.2f}G compute)")
 

@@ -19,6 +19,10 @@ def main() -> None:
     args = ap.parse_args()
     nbytes = 1 << 19 if args.quick else 1 << 21
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (fig8_ratio, fig9_throughput, table1_ratio,
                             table2_throughput, table3_usecase)
 

@@ -39,7 +39,7 @@ def main():
         heartbeat = "/tmp/repro_tiny_lm_heartbeat.json"
         log_every = 10
 
-    losses = train_cli.train_loop(A)
+    losses, _ = train_cli.train_loop(A)
     assert losses[-1] < losses[0], "loss must decrease"
     print("OK: loss decreased; checkpoints at", A.ckpt_dir)
 

@@ -61,10 +61,26 @@ from repro.runtime.async_io import AsyncBlobWriter, RetryPolicy
 from repro.runtime.fault import HostFS
 
 COMMIT_MARKER = "COMMIT"
+# raw bytes per compression dispatch: the codec's device working buffers
+# scale with it (the TPU kernel stages an int32 per container byte)
+DISPATCH_BYTES = 256 * 2**20
 
 
 def _symbol_size(dtype: np.dtype) -> int:
     return {4: 4, 2: 2, 1: 1}.get(np.dtype(dtype).itemsize, 4)
+
+
+def _dispatches(idxs, sizes):
+    """Split one geometry group into dispatches of <= DISPATCH_BYTES each
+    (in order; a leaf larger than that is a dispatch of its own)."""
+    out, cur, held = [], [], 0
+    for i, n in zip(idxs, sizes):
+        if cur and held + n > DISPATCH_BYTES:
+            out.append(cur)
+            cur, held = [], 0
+        cur.append(i)
+        held += n
+    return out + [cur] if cur else out
 
 
 def _leaf_paths(tree):
@@ -79,7 +95,12 @@ class CheckpointManager:
     compress: bool = True
     keep: int = 3
     lz_window: int = 64
-    lz_chunk: int = 4096
+    # the codec's default chunk (LZSSConfig): restores share the codec's
+    # tuned decode geometry, and every distinct leaf shape compiles a kernel
+    # on its first save and restore, which at C=4096 is the slower compile
+    # (the first save of a 4-layer llama3.2-1b-width train state took 375 s
+    # on a v5e at C=4096, compiles included)
+    lz_chunk: int = 2048
     lz_backend: str = "auto"   # compressor registry key; "auto" = the
                                # single-kernel fused-mono pipeline on TPU
     lz_decoder: str = "auto"   # decode registry key; "auto" = the single-
@@ -237,26 +258,30 @@ class CheckpointManager:
                 entries[i]["stored_bytes"] = len(raw)
                 entries[i]["file"] = fname + ".raw"
                 emit(entries[i]["file"], raw)
-        # one batched compression dispatch per dtype-class group; in async
-        # mode each group's blobs are queued as soon as its dispatch
-        # returns, so group k's host writes overlap group k+1's compression
+        # batched compression dispatches per dtype-class group, each holding
+        # at most DISPATCH_BYTES of raw leaves (a larger leaf goes alone) so
+        # the device's working buffers stay bounded; in async mode each
+        # dispatch's blobs are queued as soon as it returns, so dispatch k's
+        # host writes overlap dispatch k+1's compression
         for (s, _bucket, lossy), idxs in groups.items():
-            batch = lzss.compress_many(
-                [np.frombuffer(raws[i], np.uint8) for i in idxs],
-                self._lz_config(s, lossy=lossy),
-            )
-            for j, i in enumerate(idxs):
-                res = batch[j]
-                entries[i]["codec"] = "gpulz"
-                entries[i]["stored_bytes"] = res.total_bytes
-                entries[i]["file"] += ".gplz"
-                if lossy:
-                    # the restored bytes differ from `raw` by design, so the
-                    # raw CRC cannot gate restore; CRC the stored container
-                    # instead (still catches disk corruption before decode)
-                    entries[i]["lossy"] = True
-                    entries[i]["crc32"] = zlib.crc32(res.data.tobytes())
-                emit(entries[i]["file"], res.data.tobytes())
+            cfg = self._lz_config(s, lossy=lossy)
+            for sub in _dispatches(idxs, [len(raws[i]) for i in idxs]):
+                batch = lzss.compress_many(
+                    [np.frombuffer(raws[i], np.uint8) for i in sub], cfg
+                )
+                for j, i in enumerate(sub):
+                    res = batch[j]
+                    entries[i]["codec"] = "gpulz"
+                    entries[i]["stored_bytes"] = res.total_bytes
+                    entries[i]["file"] += ".gplz"
+                    if lossy:
+                        # the restored bytes differ from `raw` by design, so
+                        # the raw CRC cannot gate restore; CRC the stored
+                        # container instead (still catches disk corruption
+                        # before decode)
+                        entries[i]["lossy"] = True
+                        entries[i]["crc32"] = zlib.crc32(res.data.tobytes())
+                    emit(entries[i]["file"], res.data.tobytes())
         manifest["leaves"] = entries
         emit("manifest.json", json.dumps(manifest).encode())
         # the commit marker is written LAST: a crash at any earlier
@@ -344,16 +369,18 @@ class CheckpointManager:
                 # while this group is lossy): fall back per group — the
                 # container's method byte routes to the right decoder
                 decoder = "auto"
-            raws = lzss.decompress_many(
-                [blobs[n] for n in group], decoder=decoder,
-                mesh=self.lz_mesh if sharded else None,
-                batch_axis=self.lz_batch_axis if sharded else None,
-                # the pin governs restore kernels too, not just save
-                chunks_per_block=self.lz_chunks_per_block,
-            )
-            decompressed.update(
-                {n: r.tobytes() for n, r in zip(group, raws)}
-            )
+            # the same raw-byte bound per dispatch as save
+            for sub in _dispatches(group, [by_name[n]["nbytes"] for n in group]):
+                raws = lzss.decompress_many(
+                    [blobs[n] for n in sub], decoder=decoder,
+                    mesh=self.lz_mesh if sharded else None,
+                    batch_axis=self.lz_batch_axis if sharded else None,
+                    # the pin governs restore kernels too, not just save
+                    chunks_per_block=self.lz_chunks_per_block,
+                )
+                decompressed.update(
+                    {n: r.tobytes() for n, r in zip(sub, raws)}
+                )
         out = []
         for name, tmpl, sh in zip(names, leaves, sh_leaves):
             e = by_name[name]
@@ -375,11 +402,18 @@ class CheckpointManager:
         return self._load_step(template, step, shardings)
 
     def restore_latest(self, template, shardings=None):
-        """Walk back from the newest step until one restores cleanly."""
+        """Walk back from the newest step until one restores cleanly.
+
+        Only damaged data makes a step unusable: a missing or unreadable
+        file, a manifest that does not parse or lacks a leaf (``KeyError``),
+        a CRC mismatch (``IOError``) or a container that fails validation
+        (``ValueError``).  Anything else — a decode kernel that cannot
+        compile, a device error — propagates instead of reading as "no
+        checkpoint"."""
         for step in reversed(self.steps()):
             try:
                 return self._load_step(template, step, shardings)
-            except Exception as exc:  # damaged shard/manifest — try older
+            except (OSError, ValueError, KeyError) as exc:
                 print(f"[ckpt] step {step} unusable ({exc}); trying older")
         return None, -1
 

@@ -88,6 +88,21 @@ def unshuffle_xla(shuffled: jnp.ndarray) -> jnp.ndarray:
     return vals.reshape(nb * BLOCK_UNITS).astype(jnp.uint16)
 
 
+def unshuffle_words_xla(words: jnp.ndarray) -> jnp.ndarray:
+    """(N,) int32 byte pairs -> (N,) uint16 inverse transpose; N % 512 == 0.
+
+    Word ``32b + k`` of a block is plane b's bytes 2k, 2k+1 (little-endian),
+    so its bit t is bit b of unit 16k + t.
+    """
+    nb = words.shape[0] // BLOCK_UNITS
+    w = words.reshape(nb, PLANES, BLOCK_UNITS // PLANES).astype(jnp.int32)
+    t = lax.broadcasted_iota(jnp.int32, (nb, PLANES, BLOCK_UNITS // PLANES, 16), 3)
+    bits = ((w[:, :, :, None] >> t) & 1).transpose(0, 2, 3, 1)  # (nb, 32, 16, 16)
+    weight = lax.broadcasted_iota(jnp.int32, bits.shape, 3)
+    vals = jnp.sum(bits << weight, axis=3)                 # (nb, 32, 16)
+    return vals.reshape(nb * BLOCK_UNITS).astype(jnp.uint16)
+
+
 def shuffle(units: jnp.ndarray, impl=None) -> jnp.ndarray:
     """Bit-plane transpose of a padded uint16 unit stream."""
     if units.shape[0] % BLOCK_UNITS:
@@ -114,3 +129,22 @@ def unshuffle(shuffled: jnp.ndarray, impl=None) -> jnp.ndarray:
 
         return ops.bitunshuffle(shuffled)
     return unshuffle_xla(shuffled)
+
+
+def unshuffle_words(words: jnp.ndarray, impl=None) -> jnp.ndarray:
+    """``unshuffle`` of the shuffled bytes held as little-endian pairs.
+
+    ``words[i]`` is byte 2i | byte 2i+1 << 8 — how the lossy decoder holds
+    the stream (the inner container's u16 symbols), so no (N, 2) byte
+    interleave is built; length a multiple of ``BLOCK_UNITS``.
+    """
+    if words.shape[0] % BLOCK_UNITS:
+        raise ValueError(
+            f"bitshuffle inverse input must be a multiple of {BLOCK_UNITS} "
+            f"words: {words.shape[0]}"
+        )
+    if _use_pallas(impl):
+        from repro.kernels import ops
+
+        return ops.bitunshuffle_words(words)
+    return unshuffle_words_xla(words)

@@ -277,11 +277,9 @@ def canonical_tables_jax(lengths):
     guarantees at most one length matches (shorter-length prefixes of
     longer codes always land at or past ``first[l] + count[l]``).
 
-    Deliberately sort-free: ``rank``/``order`` come from a counting
-    construction over the (length, symbol) grid, not ``jnp.argsort`` —
-    XLA's sort miscompiles inside a jitted ``shard_map(check_rep=False)``
-    region on CPU host meshes (wrong decode on every shard but the first),
-    and for a 256-symbol alphabet the O(L*n) counting form is cheap anyway.
+    Sort-free: ``rank``/``order`` come from a counting construction over
+    the (length, symbol) grid, not ``jnp.argsort`` — for a 256-symbol
+    alphabet the O(L*n) counting form is cheap and needs no sort lowering.
     """
     l = jnp.asarray(lengths, jnp.int32)
     n = l.shape[0]

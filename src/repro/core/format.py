@@ -55,6 +55,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 MAGIC = (0x47, 0x50, 0x4C, 0x5A)  # "GPLZ"
@@ -295,12 +296,13 @@ def write_header_and_tables(out, *, symbol_size, window, chunk_symbols,
                             n_chunks, orig_bytes, payload_total, flag_total,
                             n_tokens, payload_sizes,
                             method=METHOD_RAW, sub_log2=0):
-    """Fill header + sections A/B of the flat int32 byte buffer ``out``."""
+    """Fill header + sections A/B of the flat byte buffer ``out`` (any
+    integer dtype; values are bytes): the region is built whole and written
+    with one contiguous update."""
     static = list(MAGIC) + [VERSION, symbol_size, window & 0xFF, window >> 8]
     static += [
         (chunk_symbols >> (8 * k)) & 0xFF for k in range(4)
     ] + [(n_chunks >> (8 * k)) & 0xFF for k in range(4)]
-    out = out.at[0:16].set(jnp.array(static, jnp.int32))
     dyn = (
         _le_bytes(orig_bytes, 8)
         + _le_bytes(payload_total, 8)
@@ -309,18 +311,22 @@ def write_header_and_tables(out, *, symbol_size, window, chunk_symbols,
         + _le_bytes(int(sub_log2), 1)
         + [jnp.zeros((), jnp.int32)] * 6
     )
-    out = out.at[16:48].set(jnp.stack(dyn).astype(jnp.int32))
     # sections A (token counts) and B (payload sizes), u32 little-endian
-    sec_a = HEADER_BYTES
-    sec_b = sec_a + 4 * n_chunks
-    for k in range(4):
-        out = out.at[sec_a + k : sec_a + 4 * n_chunks : 4].set(
-            (n_tokens >> (8 * k)) & 0xFF
-        )
-        out = out.at[sec_b + k : sec_b + 4 * n_chunks : 4].set(
-            (payload_sizes >> (8 * k)) & 0xFF
-        )
-    return out
+    shifts = 8 * jnp.arange(4, dtype=jnp.int32)
+
+    def le32(v):
+        v = jnp.asarray(v, jnp.int32).reshape(n_chunks, 1)
+        return ((v >> shifts) & 0xFF).reshape(4 * n_chunks)
+
+    region = jnp.concatenate(
+        [
+            jnp.array(static, jnp.int32),
+            jnp.stack(dyn).astype(jnp.int32),
+            le32(n_tokens),
+            le32(payload_sizes),
+        ]
+    )
+    return lax.dynamic_update_slice(out, region.astype(out.dtype), (0,))
 
 
 def parse_header(blob: np.ndarray) -> Header:
@@ -654,10 +660,10 @@ def parse_tables_jax(blob_i32, n_chunks: int):
     """
 
     def sec(base):
-        rows = blob_i32[base : base + 4 * n_chunks].reshape(n_chunks, 4)
-        return (
-            rows[:, 0] | (rows[:, 1] << 8) | (rows[:, 2] << 16)
-            | (rows[:, 3] << 24)
-        )
+        # strided byte slices, not a (n_chunks, 4) reshape: the simplifier
+        # hoists such a reshape onto the whole (padded) blob, and on TPU a
+        # 4-wide minor dimension tiles to 128 lanes -- 32x the blob's bytes
+        b = [blob_i32[base + j : base + 4 * n_chunks : 4] for j in range(4)]
+        return b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24)
 
     return sec(HEADER_BYTES), sec(HEADER_BYTES + 4 * n_chunks)

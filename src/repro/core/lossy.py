@@ -52,21 +52,42 @@ def eb_to_f32(error_bound: float) -> float:
 
 
 def _rcp(eb2) -> jnp.ndarray:
-    """The format's pre-quant scale: the f32 reciprocal of 2*eb.
+    """The format's pre-quant scale: fl32(1 / (2*eb)), correctly rounded.
 
     The encoder knows eb statically but the decoder reads it from container
     bytes; for their integer chains to agree bit-for-bit, BOTH sides must
-    lower the eb arithmetic identically.  Defining pre-quant as
+    get the same scale on every platform.  Defining pre-quant as
     ``round(x / eb2)`` breaks that: XLA strength-reduces division by a
     *constant* to a reciprocal multiply, which flips ``round`` at
     half-quantum boundaries relative to the decoder's true divide (observed
-    on CPU: carry repair off by one quantum between outliers).  So the
-    format defines pre-quant as ``round(x * fl32(1/(2*eb)))`` instead: the
-    encoder's constant fold of this divide and the decoder's runtime divide
-    are both IEEE correctly rounded — same bits — and a plain multiply has
-    no strength reduction to diverge on.
+    on CPU: carry repair off by one quantum between outliers).  A runtime
+    ``1 / eb2`` does not settle it either: the TPU's f32 divide is not
+    correctly rounded, so a decoder on the chip disagreed by an ulp with the
+    encoder's constant-folded reciprocal and mis-anchored the chain after
+    every outlier (max error 3*eb on a v5e).  So the reciprocal is computed
+    here in integer arithmetic, which every backend and the constant folder
+    evaluate alike: the 24-bit quotient of 2^47 by eb2's significand, by
+    long division six bits at a time, rounded to nearest even.  eb2 must be
+    a normal f32 below 2^126 (``LZSSConfig`` checks the bound), so the
+    result is normal.
     """
-    return jnp.float32(1.0) / jnp.asarray(eb2, jnp.float32)
+    bits = lax.bitcast_convert_type(jnp.asarray(eb2, jnp.float32), jnp.int32)
+    e = (bits >> 23) & 0xFF
+    m = (bits & 0x7FFFFF) | 0x800000
+    q = jnp.zeros_like(m)
+    r = jnp.full_like(m, 1 << 23)  # 2^23 <= m: the quotient has 24 bits
+    for _ in range(4):
+        r = r << 6
+        d = r // m
+        r = r - d * m
+        q = (q << 6) + d
+    # q = floor(2^47 / m) in [2^23, 2^24]; round on the remainder
+    up = (2 * r > m) | ((2 * r == m) & ((q & 1) == 1))
+    q = q + up.astype(jnp.int32)
+    carry = q >> 24  # 2^47 / m rounded up to 2^24: one binade higher
+    q = q >> carry
+    out = ((253 - e + carry) << 23) | (q & 0x7FFFFF)
+    return lax.bitcast_convert_type(out, jnp.float32)
 
 
 def _prequant(x: jnp.ndarray, rcp):
@@ -136,7 +157,8 @@ def compress_lossy(symbols, cfg, orig_bytes=None):
     else:
         lo = flat & 0xFFFF
         hi = (flat >> 16) & 0xFFFF
-        units_live = jnp.stack([lo, hi], axis=1).reshape(-1)
+        # interleave by interior padding, not an (n, 2) stack (see below)
+        units_live = lax.pad(lo, 0, [(0, 1, 1)]) | lax.pad(hi, 0, [(1, 0, 1)])
         sat = None
 
     units = (
@@ -145,8 +167,9 @@ def compress_lossy(symbols, cfg, orig_bytes=None):
         .set(units_live)
     )
     shuffled = bitshuffle.shuffle(units.astype(jnp.uint16)).astype(jnp.int32)
-    pairs = shuffled.reshape(-1, 2)
-    inner_live = pairs[:, 0] | (pairs[:, 1] << 8)
+    # strided halves, not a (N, 2) reshape: on TPU a 2-wide minor
+    # dimension tiles to 128 lanes (64x the stream's bytes)
+    inner_live = shuffled[0::2] | (shuffled[1::2] << 8)
     inner_c = fmt.LOSSY_INNER_CHUNK_SYMBOLS
     inner_syms = (
         jnp.zeros((inner_nc * inner_c,), jnp.int32)
@@ -283,17 +306,13 @@ def decode_blob_lossy(
             "deflate-full" if inner_method == fmt.METHOD_HUFFMAN else "auto"
         ),
     )
-    pairs = inner_syms.reshape(-1)[:units_pad]
-    shuffled = (
-        jnp.stack([pairs & 0xFF, (pairs >> 8) & 0xFF], axis=1)
-        .reshape(-1)
-        .astype(jnp.uint8)
-    )
-    units = bitshuffle.unshuffle(shuffled).astype(jnp.int32)
+    # the inner symbols ARE the shuffled bytes as little-endian pairs
+    words = inner_syms.reshape(-1)[:units_pad]
+    units = bitshuffle.unshuffle_words(words).astype(jnp.int32)
 
     if mode == fmt.LOSSY_MODE_LOSSLESS:
-        u = units[: 2 * n_elems].reshape(n_elems, 2)
-        return (u[:, 0] | (u[:, 1] << 16)).reshape(nc, c)
+        u = units[: 2 * n_elems]
+        return (u[0::2] | (u[1::2] << 16)).reshape(nc, c)
 
     eb2 = 2.0 * lax.bitcast_convert_type(u32(sec_meta), jnp.float32)
     n_out = u32(sec_meta + 8)

@@ -80,12 +80,21 @@ def _dispatch_capacity(n_bytes: int) -> int:
     return -(-max(n_bytes, 1) // _DISPATCH_QUANTUM) * _DISPATCH_QUANTUM
 
 
+# little-endian host views of S-byte symbols (``pack_symbols`` layout)
+_SYMBOL_DTYPE = {1: np.dtype("u1"), 2: np.dtype("<u2"), 4: np.dtype("<i4")}
+
+
 def _pack_padded(raw: np.ndarray, nc: int, cfg: LZSSConfig) -> jnp.ndarray:
-    """(n,) uint8 host bytes -> (nc, C) int32 symbols, zero-padded."""
+    """(n,) uint8 host bytes -> (nc, C) int32 symbols, zero-padded.
+
+    Packed on the host as a dtype view: the device receives the int32
+    symbols alone, never an (n, S) byte intermediate.
+    """
     s, c = cfg.symbol_size, cfg.chunk_symbols
     padded = np.zeros(nc * c * s, np.uint8)
     padded[: raw.size] = raw
-    return pack_symbols(jnp.asarray(padded), s).reshape(nc, c)
+    sym = padded.view(_SYMBOL_DTYPE[s]).astype(np.int32)
+    return jnp.asarray(sym.reshape(nc, c))
 
 
 def _as_bytes(data) -> np.ndarray:
@@ -177,8 +186,8 @@ def decompress(blob, decoder: str = "auto", chunks_per_block=None) -> np.ndarray
         ),
         method_params=method_params,
     )
-    out = np.asarray(unpack_symbols(symbols.reshape(-1), h.symbol_size))
-    return out[: h.orig_bytes]
+    sym = np.asarray(symbols).reshape(-1).astype(_SYMBOL_DTYPE[h.symbol_size])
+    return sym.view(np.uint8)[: h.orig_bytes]
 
 
 def compression_ratio(data, config: LZSSConfig = DEFAULT_CONFIG) -> float:
@@ -237,12 +246,21 @@ def compress_many(
     sizes = np.array([r.size for r in raws], np.int64)
     nsym_max = -(-max(1, int(sizes.max())) // s)
     nc = -(-nsym_max // c)
-    symbols = jnp.stack([_pack_padded(r, nc, config) for r in raws])
     # tuned geometry must resolve HERE, outside the jit trace (see compress)
     config = resolve_chunk_geometry(config)
-    data, totals = compress_many_chunks(
-        symbols, config, jnp.asarray(sizes, jnp.int32)
-    )
+    if len(raws) == 1:
+        # a batch of one takes the single-buffer core: its (cap,) u8 output
+        # is not padded the way a (1, cap) u8 array's tiles are on TPU (4x),
+        # which matters for a checkpoint leaf of a GiB
+        data, total = compress_chunks(
+            _pack_padded(raws[0], nc, config), config, jnp.int32(sizes[0])
+        )
+        data, totals = data[None], total[None]
+    else:
+        symbols = jnp.stack([_pack_padded(r, nc, config) for r in raws])
+        data, totals = compress_many_chunks(
+            symbols, config, jnp.asarray(sizes, jnp.int32)
+        )
     return BatchedCompressResult(
         data=np.asarray(data),
         orig_bytes=sizes,
@@ -388,9 +406,6 @@ def decompress_many(
         inner_decoder=inner_decoder,
         method_params=method_params,
     )
-    s = h0.symbol_size
     flat = np.asarray(symbols).reshape(len(blobs), -1)
-    out_bytes = np.stack(
-        [(flat >> (8 * k)) & 0xFF for k in range(s)], axis=-1
-    ).astype(np.uint8).reshape(len(blobs), -1)
+    out_bytes = flat.astype(_SYMBOL_DTYPE[h0.symbol_size]).view(np.uint8)
     return [out_bytes[i, : h.orig_bytes] for i, h in enumerate(headers)]

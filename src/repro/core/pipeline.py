@@ -146,6 +146,7 @@ from typing import Dict, Protocol
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import autotune
 from repro.core import decode as decode_mod
@@ -335,6 +336,13 @@ class LZSSConfig:
             if not math.isfinite(eb) or eb < 0:
                 raise ValueError(
                     f"lossy_eb must be a finite bound >= 0: {eb}"
+                )
+            eb32 = float(np.float32(eb))
+            if eb32 > 0 and not 2.0**-127 <= eb32 < 2.0**125:
+                # the format's integer reciprocal of 2*eb (core/lossy.py
+                # ``_rcp``) covers normal f32 results only
+                raise ValueError(
+                    f"lossy_eb must lie in [2**-127, 2**125) (or be 0): {eb}"
                 )
             object.__setattr__(self, "lossy_eb", float(eb))
             inner = resolve_backend(self.lossy_inner)
@@ -1166,14 +1174,15 @@ def _finalize_container(
 ):
     """Write header + A/B tables into a section-filled byte buffer.
 
-    ``out`` is a (cap,) int32 buffer whose flag/payload sections are already
-    in place and whose header/table region [0, HEADER_BYTES + 8*nc) carries
-    no live bytes — every emit tail produces exactly that.  Returns the
-    finished ``(buffer u8, total_bytes)``.
+    ``out`` is a (cap,) integer byte buffer whose flag/payload sections are
+    already in place and whose header/table region [0, HEADER_BYTES + 8*nc)
+    carries no live bytes — every emit tail produces exactly that.  Returns
+    the finished ``(buffer u8, total_bytes)``.  The buffer narrows to u8
+    before the header writes, so a wide working buffer is never copied.
     """
     s = cfg.symbol_size
     out = fmt.write_header_and_tables(
-        out,
+        out.astype(jnp.uint8),
         symbol_size=s,
         window=cfg.window,
         chunk_symbols=c,
@@ -1185,7 +1194,7 @@ def _finalize_container(
         payload_sizes=payload_sizes,
     )
     total = fmt.HEADER_BYTES + 8 * nc + flag_total + pay_total
-    return out.astype(jnp.uint8), total
+    return out, total
 
 
 def emit_xla(symbols, k1, cfg, orig_bytes=None):

@@ -37,7 +37,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.kernels.lz_match import _shift_right_zero
+from repro.kernels.lz_match import (
+    LANES,
+    _round_up,
+    _shift_right_zero,
+    byte_spread,
+    lane_gather,
+)
 
 
 def _ceil_log2(n: int) -> int:
@@ -69,36 +75,45 @@ def _search_last_le(sorted_rows, queries, n):
     res = jnp.zeros_like(queries)
     for shift in reversed(range(_ceil_log2(n))):
         probe = res + (1 << shift)
-        pv = jnp.take_along_axis(sorted_rows, jnp.clip(probe, 0, n - 1), axis=1)
+        pv = lane_gather(sorted_rows, jnp.clip(probe, 0, n - 1))
         res = jnp.where((probe <= n - 1) & (pv <= queries), probe, res)
     return res
 
 
-def _decode_values(flag_bytes, payload, n_tokens, *, symbol_size):
-    """(G, cb) flags + (G, C*S) payload + (G,) counts -> (G, C) symbols."""
-    g, cb = flag_bytes.shape
-    c = cb * 8
+def flag_lanes(c: int) -> int:
+    """Lane width of a chunk's flag-byte row: C/8 bytes, vreg-aligned."""
+    return _round_up(c // 8, LANES)
+
+
+def _decode_values(flag_bytes, payload, n_tokens, *, symbol_size, chunk_symbols):
+    """(G, >=C/8) flags + (G, >=C*S) payload + (G, 1) counts -> (G, C) symbols.
+
+    Flag and payload rows may be wider than the chunk's sections (vreg
+    padding); lanes past a chunk's live bytes must be zero."""
+    g = flag_bytes.shape[0]
+    c = chunk_symbols
     s = symbol_size
     t = lax.broadcasted_iota(jnp.int32, (g, c), 1)
-    active = (t < n_tokens[:, None]).astype(jnp.int32)
+    active = (t < n_tokens).astype(jnp.int32)
 
-    byte = jnp.take_along_axis(flag_bytes, t // 8, axis=1)
+    byte = byte_spread(flag_bytes, c)
     flags = ((byte >> (t % 8)) & 1) * active
 
     # token read offsets: prefix sum over [2 | S] encoded byte sizes
     read_size = jnp.where(active == 1, jnp.where(flags == 1, 2, s), 0)
     read_off = _prefix_sum_excl(read_size, t, c)
-
-    def pay_at(k):
-        return jnp.take_along_axis(
-            payload, jnp.clip(read_off + k, 0, payload.shape[1] - 1), axis=1
+    pay = [
+        lane_gather(
+            payload, jnp.clip(read_off + k, 0, payload.shape[1] - 1)
         )
+        for k in range(max(s, 2))
+    ]
 
-    ln = jnp.where(flags == 1, pay_at(0), 1) * active
-    off = jnp.where(flags == 1, pay_at(1), 0) * active
+    ln = jnp.where(flags == 1, pay[0], 1) * active
+    off = jnp.where(flags == 1, pay[1], 0) * active
     lit = jnp.zeros((g, c), jnp.int32)
     for b in range(s):
-        lit = lit + (pay_at(b) << (8 * b))
+        lit = lit + (pay[b] << (8 * b))
     lit = jnp.where(flags == 0, lit, 0)
 
     out_pos = _prefix_sum_excl(ln, t, c)  # token write starts (symbols)
@@ -110,18 +125,22 @@ def _decode_values(flag_bytes, payload, n_tokens, *, symbol_size):
     pos = jnp.where((active == 1) & (ln > 0), out_pos, c)
     token_id = _search_last_le(pos, t, c)
 
-    flag_w = jnp.take_along_axis(flags, token_id, axis=1)
-    off_w = jnp.take_along_axis(off, token_id, axis=1)
-    lit_w = jnp.take_along_axis(lit, token_id, axis=1)
-    src = jnp.where(flag_w == 1, jnp.clip(t - off_w, 0, c - 1), t)
+    # flag and offset (<= 255) share one gather
+    fo_w = lane_gather(flags | (off << 1), token_id)
+    lit_w = lane_gather(lit, token_id)
+    src = jnp.where((fo_w & 1) == 1, jnp.clip(t - (fo_w >> 1), 0, c - 1), t)
     for _ in range(_ceil_log2(c)):
-        src = jnp.take_along_axis(src, src, axis=1)
-    return jnp.take_along_axis(lit_w, src, axis=1)
+        src = lane_gather(src, src)
+    return lane_gather(lit_w, src)
 
 
 def _decode_kernel(flag_ref, pay_ref, ntok_ref, out_ref, *, symbol_size):
     out_ref[...] = _decode_values(
-        flag_ref[...], pay_ref[...], ntok_ref[...], symbol_size=symbol_size
+        flag_ref[...],
+        pay_ref[...],
+        ntok_ref[...],
+        symbol_size=symbol_size,
+        chunk_symbols=out_ref.shape[1],
     )
 
 
@@ -153,26 +172,23 @@ def lz_decode_pallas(
 
     Inputs are the per-chunk aligned sections produced by
     deflate.gather_section (int-valued; any integer dtype accepted)."""
-    f = flag_bytes.astype(jnp.int32)
-    p = payload.astype(jnp.int32)
-    nt = n_tokens.astype(jnp.int32)
-    nc, cb = f.shape
+    nc, cb = flag_bytes.shape
     c = cb * 8
     g = chunks_per_block
     pad = (-nc) % g
-    if pad:
-        f = jnp.concatenate([f, jnp.zeros((pad, cb), jnp.int32)], axis=0)
-        p = jnp.concatenate([p, jnp.zeros((pad, p.shape[1]), jnp.int32)], axis=0)
-        nt = jnp.concatenate([nt, jnp.zeros((pad,), jnp.int32)], axis=0)
+    # flag rows padded to whole vregs (lane_gather walks 128-lane blocks)
+    f = jnp.pad(flag_bytes.astype(jnp.int32), ((0, pad), (0, flag_lanes(c) - cb)))
+    p = jnp.pad(payload.astype(jnp.int32), ((0, pad), (0, 0)))
+    nt = jnp.pad(n_tokens.astype(jnp.int32), (0, pad)).reshape(-1, 1)
     npad = nc + pad
     grid = (npad // g,)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, symbol_size=symbol_size),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((g, cb), lambda i: (i, 0)),
+            pl.BlockSpec((g, f.shape[1]), lambda i: (i, 0)),
             pl.BlockSpec((g, p.shape[1]), lambda i: (i, 0)),
-            pl.BlockSpec((g,), lambda i: (i,)),
+            pl.BlockSpec((g, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((g, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((npad, c), jnp.int32),

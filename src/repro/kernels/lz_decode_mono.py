@@ -18,24 +18,23 @@ directly into VMEM scratch before running the exact ``_decode_values``
 chain of kernels/lz_decode.py.  ``deflate.gather_section`` drops out of the
 decode path entirely: ONE launch per decompress.
 
-DMA windows are fixed-width (C//8 flag bytes, C*S payload bytes per chunk —
+Windows are fixed-width (C//8 flag bytes, C*S payload bytes per chunk —
 the aligned per-chunk maxima), so a chunk's window may overrun its compact
 section into the next chunk's bytes; lane masks against the true per-chunk
 sizes zero those bytes, reproducing gather_section's zero-fill exactly.
-The wrapper pads the blob by one full window per section so the last live
-chunk's window stays in bounds and the belt-and-braces offset clamps (the
-lz_fused.py slide-phase idiom) never engage for live chunks.
+HBM rows are tiled in 128-lane units and Mosaic DMAs only whole tiles, so
+each byte-granular window is fetched as the vreg-aligned window one vreg
+wider that covers it and rotated into place in VMEM (``fetch_window``).
+The wrapper pads the blob so every such window stays in bounds.
 
 Geometry (``chunks_per_block``) resolves through core/autotune.py at the
 ops.py call site.  Byte-identity with the split decoders is enforced by
 tests/test_decode_mono.py (S×W sweep vs the oracle + golden corpus) and the
 one-launch property by its pallas-call counter test.
 
-Real-TPU caveat: the dynamic, byte-granular (unaligned) ``pl.dslice`` DMA
-starts on the ANY-space blob are validated in interpret mode only — no
-other kernel in the repo exercises this Mosaic path.  Until a real-TPU
-smoke has run (ROADMAP), ``REPRO_FUSED_MONO=0`` is the escape hatch that
-drops the TPU ``"auto"`` default back to the split ``fused`` decoder.
+The kernel compiles for TPU v5e at real sizes (tests/test_tpu_compile.py);
+``chip_smoke.py`` drives it on a chip and checks it byte-exact against the
+input.
 """
 
 from __future__ import annotations
@@ -48,67 +47,95 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import autotune
+
 from repro.core import format as fmt
-from repro.kernels.lz_decode import _ceil_log2, _decode_values
+from repro.kernels.lz_decode import (
+    _ceil_log2,
+    _decode_values,
+    flag_lanes,
+)
+from repro.kernels.lz_match import LANES, _round_up, batch_by_loop
+
+
+def fetch_window(src_ref, start, row_ref, sem, width):
+    """DMA ``width`` lanes of a (1, L) HBM row from any lane ``start``.
+
+    HBM rows are tiled in 128-lane units, so the copy reads the aligned
+    window that covers ``[start, start + width)`` into the (1, width + 128)
+    VMEM row ``row_ref`` and returns its lanes rotated down by
+    ``start % 128`` (the first ``width`` lanes are the requested bytes).
+    """
+    r = start % LANES
+    wpad = row_ref.shape[1]
+    dma = pltpu.make_async_copy(
+        src_ref.at[:, pl.ds(pl.multiple_of(start - r, LANES), wpad)],
+        row_ref,
+        sem,
+    )
+    dma.start()
+    dma.wait()
+    return pltpu.roll(row_ref[...], (wpad - r) % wpad, 1)[:, :width]
 
 
 def _mono_decode_kernel(
     fofs_ref,  # scalar prefetch: (npad,) absolute flag-window byte offsets
     pofs_ref,  # scalar prefetch: (npad,) absolute payload-window byte offsets
-    ntok_ref,  # (g,) per-chunk token counts
-    psz_ref,  # (g,) per-chunk payload byte sizes
+    ntok_ref,  # (g, 1) per-chunk token counts
+    psz_ref,  # (g, 1) per-chunk payload byte sizes
     blob_ref,  # (1, lpad) int32 container bytes, HBM-resident (ANY)
     out_ref,  # (g, C) decoded symbols
-    fbuf,  # (g, C//8) VMEM flag window
-    pbuf,  # (g, C*S) VMEM payload window
+    fbuf,  # (g, flag_lanes) VMEM flag rows
+    pbuf,  # (g, bufp) VMEM payload rows
+    fwin,  # (1, flag_lanes + 128) DMA landing row
+    pwin,  # (1, bufp + 128) DMA landing row
     sems,
     *,
     symbol_size,
     nc,
-    lpad,
 ):
     i = pl.program_id(0)
     g, c = out_ref.shape
     s = symbol_size
-    cb = c // 8
-    bufsz = c * s
+    fl = fbuf.shape[1]
+    bufp = pbuf.shape[1]
 
     # ---- fused gather: per-chunk section windows DMA'd straight from HBM --
-    for row in range(g):
+    # (a row loop, not an unrolled one, keeps the kernel's size independent
+    # of g; rows past nc hold zeros)
+    def fetch_row(row, _):
         ci = i * g + row
 
         @pl.when(ci < nc)
-        def _fetch_row(row=row, ci=ci):
-            # live offsets never clamp (the wrapper pads the blob past every
-            # window); the clamp only guards pathological table values
-            fo = jnp.minimum(fofs_ref[ci], lpad - cb)
-            po = jnp.minimum(pofs_ref[ci], lpad - bufsz)
-            fdma = pltpu.make_async_copy(
-                blob_ref.at[:, pl.dslice(fo, cb)],
-                fbuf.at[pl.dslice(row, 1), :],
-                sems.at[0],
+        def _():
+            fbuf[pl.ds(row, 1), :] = fetch_window(
+                blob_ref, fofs_ref[ci], fwin, sems.at[0], fl
             )
-            pdma = pltpu.make_async_copy(
-                blob_ref.at[:, pl.dslice(po, bufsz)],
-                pbuf.at[pl.dslice(row, 1), :],
-                sems.at[1],
+            pbuf[pl.ds(row, 1), :] = fetch_window(
+                blob_ref, pofs_ref[ci], pwin, sems.at[1], bufp
             )
-            fdma.start()
-            pdma.start()
-            fdma.wait()
-            pdma.wait()
+
+        @pl.when(ci >= nc)
+        def _():
+            fbuf[pl.ds(row, 1), :] = jnp.zeros((1, fl), jnp.int32)
+            pbuf[pl.ds(row, 1), :] = jnp.zeros((1, bufp), jnp.int32)
+
+        return 0
+
+    lax.fori_loop(0, g, fetch_row, 0)
 
     # Mask each fixed-width window to its chunk's true section size: the
-    # overrun bytes (next chunk's data, or scratch garbage on skipped pad
-    # rows) become the zeros deflate.gather_section would have produced.
+    # overrun bytes (the next chunk's data) become the zeros
+    # deflate.gather_section would have produced.
     nt = ntok_ref[...]
-    fsz = (nt + 7) // 8
-    lane_f = lax.broadcasted_iota(jnp.int32, (g, cb), 1)
-    flags = jnp.where(lane_f < fsz[:, None], fbuf[...], 0)
-    lane_p = lax.broadcasted_iota(jnp.int32, (g, bufsz), 1)
-    payload = jnp.where(lane_p < psz_ref[...][:, None], pbuf[...], 0)
+    lane_f = lax.broadcasted_iota(jnp.int32, (g, fl), 1)
+    flags = jnp.where(lane_f < (nt + 7) // 8, fbuf[...], 0)
+    lane_p = lax.broadcasted_iota(jnp.int32, (g, bufp), 1)
+    payload = jnp.where(lane_p < psz_ref[...], pbuf[...], 0)
 
-    out_ref[...] = _decode_values(flags, payload, nt, symbol_size=s)
+    out_ref[...] = _decode_values(
+        flags, payload, nt, symbol_size=s, chunk_symbols=c
+    )
 
 
 def _cost(nc, c, s):
@@ -151,16 +178,39 @@ def lz_decode_mono_pallas(
     returns.  The per-chunk section offsets are reduced to two cumsums here
     and prefetched as scalars — no gathered section arrays ever exist.
     """
+    return batch_by_loop(
+        functools.partial(
+            _decode_mono,
+            symbol_size=symbol_size,
+            chunk_symbols=chunk_symbols,
+            n_chunks=n_chunks,
+            chunks_per_block=chunks_per_block,
+            interpret=interpret,
+        )
+    )(blob, n_tokens, payload_sizes)
+
+
+def _decode_mono(
+    blob,
+    n_tokens,
+    payload_sizes,
+    *,
+    symbol_size,
+    chunk_symbols,
+    n_chunks,
+    chunks_per_block,
+    interpret,
+):
     c, s, nc = chunk_symbols, symbol_size, n_chunks
     if c % 8:
         raise ValueError(f"chunk size must be a multiple of 8: {c}")
     g = chunks_per_block
-    cb = c // 8
-    bufsz = c * s
+    fl = flag_lanes(c)
+    bufp = _round_up(c * s, LANES)
 
     b = blob.astype(jnp.int32).reshape(1, -1)
-    # pad so every fixed-width chunk window stays in bounds; lane-align
-    lpad = -(-(b.shape[1] + cb + bufsz) // 128) * 128
+    # pad so every aligned fetch window stays in bounds; lane-align
+    lpad = _round_up(b.shape[1] + bufp + 2 * LANES, LANES)
     b = jnp.pad(b, ((0, 0), (0, lpad - b.shape[1])))
 
     nt = n_tokens.astype(jnp.int32)
@@ -169,40 +219,43 @@ def lz_decode_mono_pallas(
     fcs = jnp.cumsum(fsz)
     pcs = jnp.cumsum(psz)
     sec_flags = fmt.HEADER_BYTES + 8 * nc
-    fofs = sec_flags + fcs - fsz  # absolute flag-section starts
-    pofs = sec_flags + fcs[-1] + pcs - psz  # absolute payload starts
+    # absolute section starts; the clamp only guards pathological tables
+    # (a validated container never comes near it)
+    fofs = jnp.clip(sec_flags + fcs - fsz, 0, lpad - fl - LANES)
+    pofs = jnp.clip(sec_flags + fcs[-1] + pcs - psz, 0, lpad - bufp - LANES)
 
     pad = (-nc) % g
-    if pad:
-        z = jnp.zeros((pad,), jnp.int32)
-        nt = jnp.concatenate([nt, z])
-        psz = jnp.concatenate([psz, z])
-        fofs = jnp.concatenate([fofs, z])
-        pofs = jnp.concatenate([pofs, z])
+    nt = jnp.pad(nt, (0, pad)).reshape(-1, 1)
+    psz = jnp.pad(psz, (0, pad)).reshape(-1, 1)
+    fofs = jnp.pad(fofs, (0, pad))
+    pofs = jnp.pad(pofs, (0, pad))
     npad = nc + pad
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(npad // g,),
         in_specs=[
-            pl.BlockSpec((g,), lambda i, fo_, po_: (i,)),
-            pl.BlockSpec((g,), lambda i, fo_, po_: (i,)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((g, 1), lambda i, fo_, po_: (i, 0)),
+            pl.BlockSpec((g, 1), lambda i, fo_, po_: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((g, c), lambda i, fo_, po_: (i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, cb), jnp.int32),
-            pltpu.VMEM((g, bufsz), jnp.int32),
+            pltpu.VMEM((g, fl), jnp.int32),
+            pltpu.VMEM((g, bufp), jnp.int32),
+            pltpu.VMEM((1, fl + LANES), jnp.int32),
+            pltpu.VMEM((1, bufp + LANES), jnp.int32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(
-            _mono_decode_kernel, symbol_size=s, nc=nc, lpad=lpad
-        ),
+        functools.partial(_mono_decode_kernel, symbol_size=s, nc=nc),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((npad, c), jnp.int32),
         cost_estimate=_cost(npad, c, s),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=autotune.VMEM_LIMIT_BYTES
+        ),
         interpret=interpret,
     )(fofs, pofs, nt, psz, b)
     return out[:nc]

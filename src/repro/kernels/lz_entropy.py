@@ -14,7 +14,7 @@ Two kernels back the ``deflate-full`` backend/decoder pair:
     lz_decode_mono.py idiom) and each grid step DMAs one fixed-width
     bitstream window per gap-array sub-block into VMEM at scalar-prefetched
     byte offsets.  Every sub-block lane then walks exactly ``sub``
-    codewords from its entry point: a 24-bit window is gathered at the
+    codewords from its entry point: a 24-bit window is read at the
     lane's bit offset, all 15 candidate lengths are range-tested against
     the canonical ``first``/``count`` tables at once (the prefix property
     guarantees a unique hit), and the decode table maps the hit to its
@@ -24,13 +24,13 @@ Two kernels back the ``deflate-full`` backend/decoder pair:
 
 The decode table rides in one (8, 128) int32 block: rows 0-2 are the
 ``first`` / ``count`` / ``base`` per-length tables (16 live lanes), rows
-3-4 the 256-entry symbol ``order`` map split across two lanes' rows.
+3-4 the 256-entry symbol ``order`` map split across two 128-lane rows.
 
-Real-TPU caveat (same class as lz_decode_mono.py, documented in
-EXPERIMENTS.md): the per-lane ``take_along_axis`` window gathers and the
-dynamic per-codeword column store are validated in interpret mode only;
-``REPRO_ENTROPY_PALLAS=0`` drops the TPU default back to the XLA
-scan/scatter paths in core/entropy.py until a real-TPU smoke has run.
+Mosaic has no per-lane gather across vregs and no single-lane dynamic
+store, so table lookups and the 24-bit window read are one-hot lane
+reductions, and decoded bytes leave in vreg-aligned 128-lane blocks.  Both
+kernels compile for TPU v5e at real sizes (tests/test_tpu_compile.py);
+``chip_smoke.py``'s ``deflate-full`` round trips drive them on a chip.
 """
 
 from __future__ import annotations
@@ -43,13 +43,12 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.lz_decode_mono import fetch_window
+from repro.kernels.lz_match import _round_up, batch_by_loop
+
 HIST_TILE = 1024  # bytes per histogram grid step (8 x 128 int32 lanes)
 N_SYMBOLS = 256
 MAX_CODE_LEN = 15
-
-
-def _round_up(n: int, m: int) -> int:
-    return -(-n // m) * m
 
 
 def window_bytes(sub: int) -> int:
@@ -87,6 +86,12 @@ def byte_histogram_pallas(buf, start, length, *, interpret=False):
     grid is sequential over 1024-byte tiles, accumulating into one
     revisited (1, 256) block.
     """
+    return batch_by_loop(functools.partial(_histogram, interpret=interpret))(
+        jnp.asarray(buf), jnp.asarray(start), jnp.asarray(length)
+    )
+
+
+def _histogram(buf, start, length, *, interpret):
     b = jnp.asarray(buf, jnp.int32).reshape(1, -1)
     npad = _round_up(max(b.shape[1], 1), HIST_TILE)
     b = jnp.pad(b, ((0, 0), (0, npad - b.shape[1])))
@@ -117,62 +122,96 @@ def byte_histogram_pallas(buf, start, length, *, interpret=False):
 
 def _gap_decode_kernel(
     wstart_ref,  # scalar prefetch: (npad,) absolute window byte starts
-    rem_ref,  # (g,) entry-point bit remainders within each window
+    rem_ref,  # (g, 1) entry-point bit remainders within each window
     tab_ref,  # (8, 128) packed decode table (see module docstring)
     blob_ref,  # (1, lpad) container bytes, HBM-resident (ANY)
     out_ref,  # (g, sub) decoded bytes
     wbuf,  # (g, win) VMEM bitstream windows
+    wrow,  # (1, win + 128) DMA landing row
     sems,
     *,
-    sub,
-    win,
     nsub,
 ):
     i = pl.program_id(0)
-    g = out_ref.shape[0]
+    g, sub = out_ref.shape
+    win = wbuf.shape[1]
 
-    for row in range(g):
+    def fetch_row(row, _):
         li = i * g + row
 
         @pl.when(li < nsub)
-        def _fetch(row=row, li=li):
-            dma = pltpu.make_async_copy(
-                blob_ref.at[:, pl.dslice(wstart_ref[li], win)],
-                wbuf.at[pl.dslice(row, 1), :],
-                sems.at[0],
+        def _():
+            wbuf[pl.ds(row, 1), :] = fetch_window(
+                blob_ref, wstart_ref[li], wrow, sems.at[0], win
             )
-            dma.start()
-            dma.wait()
 
-    first = tab_ref[0, : MAX_CODE_LEN + 1]
-    count = tab_ref[1, : MAX_CODE_LEN + 1]
-    base = tab_ref[2, : MAX_CODE_LEN + 1]
-    order = tab_ref[3:5, :].reshape(N_SYMBOLS)
+        @pl.when(li >= nsub)
+        def _():
+            wbuf[pl.ds(row, 1), :] = jnp.zeros((1, win), jnp.int32)
+
+        return 0
+
+    lax.fori_loop(0, g, fetch_row, 0)
+
+    # Table rows span all 128 lanes; lane l of ``first``/``count``/``base``
+    # is code length l, live for l in [1, MAX_CODE_LEN].  Every lookup is a
+    # one-hot lane reduction (per-lane scalars cannot index vregs).
+    lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    live_len = (lane >= 1) & (lane <= MAX_CODE_LEN)
+    first = tab_ref[0:1, :]
+    count = tab_ref[1:2, :]
+    base = tab_ref[2:3, :]
+    order_lo = tab_ref[3:4, :]
+    order_hi = tab_ref[4:5, :]
+    shift = jnp.clip(MAX_CODE_LEN - lane, 0, MAX_CODE_LEN)
+    # 24-bit lookahead at every byte lane: bytes b, b+1, b+2 (zero past win)
     w = wbuf[...] & 0xFF
-    # iota-built constants: a captured jnp.arange would be a trace-level
-    # constant, which pallas_call rejects
-    ls = 1 + lax.broadcasted_iota(jnp.int32, (1, MAX_CODE_LEN), 1)
-    fc = jnp.take(first, ls)  # (1, 15) first codeword per length
-    cn = jnp.take(count, ls)
+    wl = lax.broadcasted_iota(jnp.int32, (g, win), 1)
+    w24 = (
+        (w << 16)
+        | (jnp.where(wl < win - 1, pltpu.roll(w, win - 1, 1), 0) << 8)
+        | jnp.where(wl < win - 2, pltpu.roll(w, win - 2, 1), 0)
+    )
 
-    def step(t, off):
-        byte = off >> 3
-        look = lax.broadcasted_iota(jnp.int32, (1, 3), 1)
-        idx = jnp.clip(byte[:, None] + look, 0, win - 1)
-        b3 = jnp.take_along_axis(w, idx, axis=1)
-        w24 = (b3[:, 0] << 16) | (b3[:, 1] << 8) | b3[:, 2]
-        win15 = (w24 >> (9 - (off & 7))) & ((1 << MAX_CODE_LEN) - 1)
-        cand = win15[:, None] >> (MAX_CODE_LEN - ls)
-        ok = (cand >= fc) & (cand - fc < cn)
-        sel = jnp.argmax(ok, axis=1)  # unique hit: canonical prefix property
-        lsel = sel + 1
-        csel = jnp.take_along_axis(cand, sel[:, None], axis=1)[:, 0]
-        sidx = jnp.take(base, lsel) + csel - jnp.take(first, lsel)
-        sym = jnp.take(order, jnp.clip(sidx, 0, N_SYMBOLS - 1))
-        out_ref[:, pl.dslice(t, 1)] = sym[:, None]
-        return off + lsel
+    def codeword(off):
+        byte = jnp.clip(off >> 3, 0, win - 1)
+        look = jnp.sum(jnp.where(wl == byte, w24, 0), axis=1, keepdims=True)
+        win15 = (look >> (9 - (off & 7))) & ((1 << MAX_CODE_LEN) - 1)
+        cand = win15 >> shift
+        ok = jnp.where(live_len & (cand >= first) & (cand - first < count), 1, 0)
+        # unique hit by the canonical prefix property; with no hit, length
+        # 1 (argmax's pick) so garbage lanes advance like the XLA fallback
+        any_hit = jnp.max(ok, axis=1, keepdims=True)
+        hit = jnp.where(any_hit == 1, ok, jnp.where(lane == 1, 1, 0))
+        lsel = jnp.sum(hit * lane, axis=1, keepdims=True)
+        sidx = jnp.sum(hit * (base + cand - first), axis=1, keepdims=True)
+        sidx = jnp.clip(sidx, 0, N_SYMBOLS - 1)
+        sym = jnp.sum(
+            jnp.where(lane == sidx, order_lo, 0)
+            + jnp.where(lane + 128 == sidx, order_hi, 0),
+            axis=1,
+            keepdims=True,
+        )
+        return off + lsel, sym
 
-    lax.fori_loop(0, sub, step, rem_ref[...])
+    # decoded bytes go out a vreg-aligned block at a time (no dynamic
+    # single-lane stores): each block's lanes fill by one-hot selects
+    bw = 128 if sub % 128 == 0 else sub
+    blk_lane = lax.broadcasted_iota(jnp.int32, (g, bw), 1)
+
+    def block(b, off):
+        def step(j, carry):
+            off, acc = carry
+            off, sym = codeword(off)
+            return off, jnp.where(blk_lane == j, sym, acc)
+
+        off, acc = lax.fori_loop(
+            0, bw, step, (off, jnp.zeros((g, bw), jnp.int32))
+        )
+        out_ref[:, pl.ds(pl.multiple_of(b * bw, bw), bw)] = acc
+        return off
+
+    lax.fori_loop(0, sub // bw, block, rem_ref[...])
 
 
 @functools.partial(
@@ -202,20 +241,46 @@ def huffman_gap_decode_pallas(
     bytes; lanes beyond a section's live byte count decode garbage the
     caller masks (exactly like the XLA scan fallback).
     """
+    return batch_by_loop(
+        functools.partial(
+            _gap_decode,
+            sub=sub,
+            chunks_per_block=chunks_per_block,
+            interpret=interpret,
+        )
+    )(
+        *(
+            jnp.asarray(a)
+            for a in (blob, wstarts, rems, first, count, base, order)
+        )
+    )
+
+
+def _gap_decode(
+    blob,
+    wstarts,
+    rems,
+    first,
+    count,
+    base,
+    order,
+    *,
+    sub,
+    chunks_per_block,
+    interpret,
+):
     g = chunks_per_block
     win = window_bytes(sub)
     b = jnp.asarray(blob, jnp.int32).reshape(1, -1)
-    lpad = _round_up(b.shape[1] + win, 128)
+    lpad = _round_up(b.shape[1] + win + 2 * 128, 128)
     b = jnp.pad(b, ((0, 0), (0, lpad - b.shape[1])))
 
     nsub = wstarts.shape[0]
-    ws = jnp.clip(jnp.asarray(wstarts, jnp.int32), 0, lpad - win)
+    ws = jnp.clip(jnp.asarray(wstarts, jnp.int32), 0, lpad - win - 128)
     rm = jnp.asarray(rems, jnp.int32)
     pad = (-nsub) % g
-    if pad:
-        z = jnp.zeros((pad,), jnp.int32)
-        ws = jnp.concatenate([ws, z])
-        rm = jnp.concatenate([rm, z])
+    ws = jnp.pad(ws, (0, pad))
+    rm = jnp.pad(rm, (0, pad)).reshape(-1, 1)
     npad = nsub + pad
 
     tab = jnp.zeros((8, 128), jnp.int32)
@@ -228,18 +293,19 @@ def huffman_gap_decode_pallas(
         num_scalar_prefetch=1,
         grid=(npad // g,),
         in_specs=[
-            pl.BlockSpec((g,), lambda i, w_: (i,)),
+            pl.BlockSpec((g, 1), lambda i, w_: (i, 0)),
             pl.BlockSpec((8, 128), lambda i, w_: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((g, sub), lambda i, w_: (i, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, win), jnp.int32),
+            pltpu.VMEM((1, win + 128), jnp.int32),
             pltpu.SemaphoreType.DMA((1,)),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_gap_decode_kernel, sub=sub, win=win, nsub=nsub),
+        functools.partial(_gap_decode_kernel, nsub=nsub),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((npad, sub), jnp.int32),
         cost_estimate=pl.CostEstimate(

@@ -9,7 +9,8 @@ TPU mapping of the paper's fused kernel (§3.3.2):
   chunk per thread block             ->  ``chunks_per_block`` chunks stacked on
                                          sublanes (fills the 8x128 VREG tile)
   one encode thread per block        ->  in-kernel selection walk over lanes
-                                         (dynamic column load/store)
+                                         (aligned 128-lane blocks, one-hot
+                                         lane reductions per position)
   shared-mem local prefix sum        ->  in-VMEM log-doubling prefix sum
 
 Everything between the symbol load and the (len/off/emitted/local-offset)
@@ -18,7 +19,11 @@ touch HBM.  That is precisely the paper's two-pass-prefix-sum + kernel-fusion
 insight (their Fig. 4 (c) vs (d)); the unfused XLA pipeline in core/ is the
 workflow-(c) baseline we compare against in EXPERIMENTS.md.
 
-Kernels are validated in interpret mode against kernels/ref.py (pure jnp).
+Kernels are validated in interpret mode against kernels/ref.py (pure jnp)
+and compiled for TPU v5e at real sizes by tests/test_tpu_compile.py.  Only
+what Mosaic lowers is used: lane rotates (``pltpu.roll``), gathers within
+one vreg (``lane_gather`` walks wider rows block by block), lane slices at
+128-aligned offsets, and (g, 1) column blocks for per-chunk tables.
 """
 
 from __future__ import annotations
@@ -29,8 +34,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 MAX_LEN_CAP = 255
+LANES = 128  # TPU vreg lane width: Mosaic gathers and rolls within one
 
 
 def _levels(window: int, max_len: int) -> int:
@@ -41,13 +48,117 @@ def _levels(window: int, max_len: int) -> int:
     return k
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def _shift_left_zero(x, stride, idx, c):
     """out[..., i] = x[..., i + stride] with zero fill (roll + mask)."""
-    return jnp.where(idx < c - stride, jnp.roll(x, -stride, axis=-1), 0)
+    if stride >= c:
+        return jnp.zeros_like(x)
+    return jnp.where(idx < c - stride, pltpu.roll(x, c - stride, 1), 0)
 
 
 def _shift_right_zero(x, stride, idx):
-    return jnp.where(idx >= stride, jnp.roll(x, stride, axis=-1), 0)
+    c = x.shape[-1]
+    if stride >= c:
+        return jnp.zeros_like(x)
+    return jnp.where(idx >= stride, pltpu.roll(x, stride, 1), 0)
+
+
+def byte_spread(rows, c):
+    """(g, >=C/8) byte rows -> (g, C) with lane t holding byte t // 8.
+
+    A 0/1 selection matrix on the MXU: bytes are exact in bf16 and the
+    f32 accumulator sums one term.  (The equivalent constant-index
+    ``take_along_axis`` crashes the TPU compiler for blocks taller than
+    one vreg.)"""
+    nb = rows.shape[1]
+    b = lax.broadcasted_iota(jnp.int32, (nb, c), 0)
+    t = lax.broadcasted_iota(jnp.int32, (nb, c), 1)
+    sel = (t // 8 == b).astype(jnp.bfloat16)
+    out = jnp.dot(
+        rows.astype(jnp.bfloat16), sel, preferred_element_type=jnp.float32
+    )
+    return out.astype(jnp.int32)
+
+
+def bit_pack(bits, nb):
+    """(g, C) 0/1 lanes -> (g, nb) bytes, bit j of byte b = lane 8b + j.
+
+    The inverse of ``byte_spread`` as one MXU product with power-of-two
+    weights (exact in bf16; at most eight terms per f32 sum).  Bytes past
+    C/8 are zero."""
+    c = bits.shape[1]
+    t = lax.broadcasted_iota(jnp.int32, (c, nb), 0)
+    b = lax.broadcasted_iota(jnp.int32, (c, nb), 1)
+    weight = jnp.where(t // 8 == b, 1 << (t % 8), 0).astype(jnp.bfloat16)
+    out = jnp.dot(
+        bits.astype(jnp.bfloat16), weight, preferred_element_type=jnp.float32
+    )
+    return out.astype(jnp.int32)
+
+
+def lane_gather(x, idx):
+    """``out[r, j] = x[r, idx[r, j]]`` for (g, n) rows and (g, m) indices.
+
+    Mosaic lowers ``take_along_axis`` only within one 128-lane vreg, so a
+    wider row is walked in 128-lane source blocks: each block is staged
+    through VMEM scratch, gathered with the low 7 index bits and kept where
+    the high bits name that block.  Indices outside ``[0, n)`` give 0.
+    Rows of one vreg (and lane widths that are not vreg multiples, which
+    only the interpreter sees) gather directly.
+    """
+    g, n = x.shape
+    m = idx.shape[1]
+    if (n == m and n <= LANES) or n % LANES or m % LANES:
+        return jnp.take_along_axis(x, idx, axis=1, mode="fill", fill_value=0)
+    lo = idx & (LANES - 1)
+    hi = idx >> 7
+    nout = m // LANES
+
+    def run(src):
+        src[...] = x
+
+        def block(j, out):
+            blk = src[:, pl.ds(pl.multiple_of(j * LANES, LANES), LANES)]
+            got = jnp.concatenate(
+                [
+                    jnp.take_along_axis(
+                        blk, lo[:, o * LANES : (o + 1) * LANES], axis=1
+                    )
+                    for o in range(nout)
+                ],
+                axis=1,
+            )
+            return jnp.where(hi == j, got, out)
+
+        return lax.fori_loop(0, n // LANES, block, jnp.zeros((g, m), x.dtype))
+
+    return pl.run_scoped(run, pltpu.VMEM((g, n), x.dtype))
+
+
+def batch_by_loop(fn):
+    """``fn`` whose ``vmap`` is a loop of ``fn`` over the batch.
+
+    Pallas batches a ``pallas_call`` by adding a grid dimension, which the
+    TPU lowering refuses for HBM-resident (``memory_space=ANY``) operands;
+    the batched entry points (``compress_many``, the KV tier, the gradient
+    exchange) vmap the kernels, so those kernels run one buffer at a time.
+    Nested vmaps nest the loops.
+    """
+    looped = jax.custom_batching.custom_vmap(fn)
+
+    @looped.def_vmap
+    def rule(axis_size, in_batched, *args):
+        def one(batched):
+            it = iter(batched)
+            return looped(*[next(it) if b else a for a, b in zip(args, in_batched)])
+
+        out = lax.map(one, [a for a, b in zip(args, in_batched) if b])
+        return out, jax.tree.map(lambda _: True, out)
+
+    return looped
 
 
 def _match_values(x, *, window, max_len):
@@ -63,7 +174,9 @@ def _match_values(x, *, window, max_len):
 
     def body_for(levels):
         def body(d, best):
-            shifted = jnp.roll(x, d, axis=-1)  # wrapped lanes masked below
+            # a traced-shift lane rotate (jnp.roll would lower to a
+            # dynamic_slice Mosaic lacks); wrapped lanes masked below
+            shifted = pltpu.roll(x, d % c, 1)
             eq = ((x == shifted) & (idx >= d)).astype(jnp.int32)
             r = eq
             for k in range(levels):
@@ -100,21 +213,38 @@ def _select_and_scan(len_ref, emit_ref, lengths, *, min_match, symbol_size):
     the walk's dynamic-column stores go through and holds the 0/1 emitted
     mask on return.  Returns ``(emitted, use_match, sizes, local_off,
     payload_sizes, n_tokens)`` values — the per-position arrays are (g, C),
-    the per-chunk reductions (g,).  Shared by the fused Kernel I below and
+    the per-chunk reductions (g, 1).  Shared by the fused Kernel I below and
     the single-kernel compressor (lz_fused.py).
     """
     g, c = len_ref.shape
+    w = LANES if c % LANES == 0 else c
+    lane = lax.broadcasted_iota(jnp.int32, (g, w), 1)
 
-    # --- encode walk (paper: one thread per block; here: lanes via dynamic
-    # column access, all `g` chunks in lockstep on sublanes) ----------------
-    def body(i, next_pos):
-        len_i = pl.load(len_ref, (slice(None), pl.dslice(i, 1)))
-        emit = next_pos == i
-        step = jnp.where(len_i >= min_match, len_i, 1)
-        pl.store(emit_ref, (slice(None), pl.dslice(i, 1)), emit.astype(jnp.int32))
-        return jnp.where(emit, i + step, next_pos)
+    # --- encode walk (paper: one thread per block; here: all `g` chunks in
+    # lockstep on sublanes).  Mosaic indexes lanes dynamically only at vreg
+    # granularity, so the walk loads one aligned 128-lane block, steps
+    # through its lanes with one-hot lane reductions, and stores the block's
+    # emitted mask back whole.
+    def block(b, next_pos):
+        start = pl.multiple_of(b * w, w)
+        lens = len_ref[:, pl.ds(start, w)]
 
-    lax.fori_loop(0, c, body, jnp.zeros((g, 1), jnp.int32))
+        def position(j, carry):
+            next_pos, emit = carry
+            hit = lane == j
+            len_j = jnp.sum(jnp.where(hit, lens, 0), axis=1, keepdims=True)
+            here = next_pos == start + j
+            step = jnp.where(len_j >= min_match, len_j, 1)
+            emit = jnp.where(hit & here, 1, emit)
+            return jnp.where(here, start + j + step, next_pos), emit
+
+        next_pos, emit = lax.fori_loop(
+            0, w, position, (next_pos, jnp.zeros((g, w), jnp.int32))
+        )
+        emit_ref[:, pl.ds(start, w)] = emit
+        return next_pos
+
+    lax.fori_loop(0, c // w, block, jnp.zeros((g, 1), jnp.int32))
 
     # --- local prefix sum (paper's up/down-sweep == lane-shift doubling) ---
     emitted = emit_ref[...] == 1
@@ -130,7 +260,7 @@ def _select_and_scan(len_ref, emit_ref, lengths, *, min_match, symbol_size):
         incl = incl + _shift_right_zero(incl, k, idx)
         ntok = ntok + _shift_right_zero(ntok, k, idx)
         k *= 2
-    return emitted, use_match, sizes, incl - sizes, incl[:, c - 1], ntok[:, c - 1]
+    return emitted, use_match, sizes, incl - sizes, incl[:, c - 1 :], ntok[:, c - 1 :]
 
 
 def _fused_kernel(
@@ -232,9 +362,11 @@ def lz_kernel1_pallas(
     g = chunks_per_block
     grid = (npad // g,)
     spec2d = pl.BlockSpec((g, c), lambda i: (i, 0))
-    spec1d = pl.BlockSpec((g,), lambda i: (i,))
+    # per-chunk tables ride as (g, 1) column blocks: Mosaic tiles the last
+    # two dims, so a rank-1 (g,) block would need g to be a lane multiple
+    spec1d = pl.BlockSpec((g, 1), lambda i: (i, 0))
     sds2 = jax.ShapeDtypeStruct((npad, c), jnp.int32)
-    sds1 = jax.ShapeDtypeStruct((npad,), jnp.int32)
+    sds1 = jax.ShapeDtypeStruct((npad, 1), jnp.int32)
     out = pl.pallas_call(
         functools.partial(
             _fused_kernel,
@@ -256,6 +388,6 @@ def lz_kernel1_pallas(
         offsets=offsets[:nc],
         emitted=emitted[:nc] == 1,
         local_off=local_off[:nc],
-        payload_sizes=paysz[:nc],
-        n_tokens=ntok[:nc],
+        payload_sizes=paysz[:nc, 0],
+        n_tokens=ntok[:nc, 0],
     )

@@ -23,7 +23,8 @@ Two passes, mirroring the paper's Kernel II -> III split:
       and payload bytes in VMEM from the Kernel-I arrays (a rank->position
       binary search — the gather-friendly inverse of ``pack_flags``'s
       scatter-add, which has no efficient Mosaic lowering) and blends each
-      chunk's compact prefix into the output blob at its global offset.
+      chunk's compact prefix into the output blob at its global offset
+      (read-modify-write of the vreg-aligned window that covers it).
       The per-chunk offsets ride in as scalar-prefetch operands
       (``pltpu.PrefetchScalarGridSpec``), so every dynamic store address is
       an SMEM scalar read; the blob block is revisited across the grid and
@@ -31,9 +32,10 @@ Two passes, mirroring the paper's Kernel II -> III split:
 
 Like the other kernels, correctness is validated in interpret mode against
 the XLA tail (tests/test_kernels.py); byte-identity of full containers is
-enforced by tests/test_pipeline.py.  Real-TPU caveats (VMEM residency of
-the whole blob, Mosaic dynamic-lane-slice lowering) are tracked in
-ROADMAP.md.
+enforced by tests/test_pipeline.py, and tests/test_tpu_compile.py compiles
+it for TPU v5e.  The whole blob is one VMEM-resident block, so containers
+are bounded by ``SCATTER_VMEM_BYTES`` (a clear ValueError above it); the
+``fused-mono`` compressor has no such bound.
 """
 
 from __future__ import annotations
@@ -46,8 +48,23 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lz_decode import _ceil_log2, _prefix_sum_excl, _search_last_le
+from repro.kernels.lz_decode import (
+    _ceil_log2,
+    _prefix_sum_excl,
+    _search_last_le,
+    flag_lanes,
+)
+from repro.kernels.lz_match import (
+    LANES,
+    _round_up,
+    batch_by_loop,
+    bit_pack,
+    lane_gather,
+)
 
+
+# The deflate-scatter keeps the whole (1, cap) blob in one VMEM block.
+SCATTER_VMEM_BYTES = 32 * 2**20
 
 # ---------------------------------------------------- pass 1: Kernel II
 
@@ -106,13 +123,13 @@ def _build_sections(
 ):
     """Rebuild the per-chunk compact section bytes from Kernel-I outputs.
 
-    All inputs are int32 values: (g, C) per-position arrays plus the (g,)
-    per-chunk ``ntok``/``psz`` reductions.  Returns ``(flag_bytes (g, C//8),
-    payload (g, C*S))`` with zeros past each chunk's live size — everything
-    stays in registers/VMEM (rank->position binary search instead of
-    ``pack_flags``'s scatter-add, which has no efficient Mosaic lowering).
-    Shared by the deflate-scatter kernel below and the single-kernel
-    compressor (lz_fused.py).
+    All inputs are int32 values: (g, C) per-position arrays plus the (g, 1)
+    per-chunk ``ntok``/``psz`` reductions.  Returns ``(flag_bytes (g,
+    flag_lanes(C)), payload (g, round_up(C*S, 128)))`` with zeros past each
+    chunk's live size — everything stays in registers/VMEM (rank->position
+    binary search instead of ``pack_flags``'s scatter-add, which has no
+    efficient Mosaic lowering).  Shared by the deflate-scatter kernel below
+    and the single-kernel compressor (lz_fused.py).
     """
     g, c = sym.shape
     s = symbol_size
@@ -125,35 +142,46 @@ def _build_sections(
     # ranks[i] <= r (pack_flags computes the same map as a scatter-add).
     ranks = _prefix_sum_excl(emitted, t, c)
     tok_pos = _search_last_le(ranks, t, c)
+    valid_r = t < ntok
 
-    valid_r = (t < ntok[:, None]).astype(jnp.int32)
-    fbit = jnp.take_along_axis(um, tok_pos, axis=1) * valid_r
+    # per-token fields in rank space; pointer bytes (<= 255) and the kind
+    # bit share one word so the byte-space expansion below gathers it once
+    ptr_r = lane_gather(lengths | (offsets << 8) | (um << 16), tok_pos)
+    fbit = jnp.where(valid_r, ptr_r >> 16, 0)
+    sym_r = lane_gather(sym, tok_pos)
 
     # flag bytes: bit j of byte b is token (8b+j)'s kind (format.py layout)
-    bidx = lax.broadcasted_iota(jnp.int32, (g, cb), 1)
-    fbyte = jnp.zeros((g, cb), jnp.int32)
-    for j in range(8):
-        fbyte = fbyte + (jnp.take_along_axis(fbit, 8 * bidx + j, axis=1) << j)
+    fbyte = bit_pack(fbit, flag_lanes(c))
 
     # token write offsets in rank space (sentinel bufsz keeps the row
     # sorted past n_tokens), then payload byte p -> covering token
-    lo_r = jnp.take_along_axis(local_off, tok_pos, axis=1)
-    tok_off = jnp.where(valid_r == 1, lo_r, bufsz)
-    p = lax.broadcasted_iota(jnp.int32, (g, bufsz), 1)
+    tok_off = jnp.where(valid_r, lane_gather(local_off, tok_pos), bufsz)
+    p = lax.broadcasted_iota(jnp.int32, (g, _round_up(bufsz, LANES)), 1)
     r_of_p = _search_last_le(tok_off, p, c)
-    i_p = jnp.take_along_axis(tok_pos, r_of_p, axis=1)
-    b_p = p - jnp.take_along_axis(tok_off, r_of_p, axis=1)
-    um_p = jnp.take_along_axis(um, i_p, axis=1)
-    ptr = jnp.where(
-        b_p == 0,
-        jnp.take_along_axis(lengths, i_p, axis=1),
-        jnp.take_along_axis(offsets, i_p, axis=1),
-    )
-    sym_p = jnp.take_along_axis(sym, i_p, axis=1)
+    b_p = p - lane_gather(tok_off, r_of_p)
+    ptr_p = lane_gather(ptr_r, r_of_p)
+    sym_p = lane_gather(sym_r, r_of_p)
+    ptr = jnp.where(b_p == 0, ptr_p & 0xFF, (ptr_p >> 8) & 0xFF)
     lit = (sym_p >> (8 * jnp.clip(b_p, 0, 3))) & 0xFF
-    val = jnp.where(um_p == 1, ptr, lit)
-    prow = jnp.where(p < psz[:, None], val, 0)
+    val = jnp.where((ptr_p >> 16) == 1, ptr, lit)
+    prow = jnp.where(p < psz, val, 0)
     return fbyte, prow
+
+
+def _blend_window(win_ref, data, r, n):
+    """Write the first ``n`` lanes of ``data`` at lane ``r`` of ``win_ref``.
+
+    ``win_ref`` is a (1, W) VMEM row holding a vreg-aligned window of the
+    destination; every other lane keeps what it holds.  A byte-granular
+    store built from a lane rotate and a mask — Mosaic has no unaligned
+    lane store.
+    """
+    w = win_ref.shape[1]
+    pad = w - data.shape[1]
+    wide = jnp.concatenate([data, jnp.zeros((1, pad), data.dtype)], axis=1)
+    lane = lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    live = (lane >= r) & (lane < r + n)
+    win_ref[...] = jnp.where(live, pltpu.roll(wide, r, 1), win_ref[...])
 
 
 def _scatter_kernel(
@@ -168,10 +196,11 @@ def _scatter_kernel(
     nt_ref,
     ps_ref,
     out_ref,
+    fwin,
+    pwin,
     *,
     symbol_size,
     sec_flags,
-    cap,
 ):
     i = pl.program_id(0)
 
@@ -180,8 +209,6 @@ def _scatter_kernel(
         out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
     g, c = sym_ref.shape
-    cb = c // 8
-    bufsz = c * symbol_size
     fbyte, prow = _build_sections(
         sym_ref[...],
         len_ref[...],
@@ -195,30 +222,31 @@ def _scatter_kernel(
     )
 
     # Kernel III: blend each chunk's compact prefix into the blob at its
-    # global offset (RMW merge over a full-width window; grid steps run
-    # sequentially, so later chunks re-blend their own bytes).  Offsets
-    # are SMEM scalar reads; clamping keeps the padded rows' zero-width
-    # windows in bounds even for all-literal worst cases.
-    jf = lax.broadcasted_iota(jnp.int32, (1, cb), 1)
-    jp = lax.broadcasted_iota(jnp.int32, (1, bufsz), 1)
+    # global offset (read-modify-write of a vreg-aligned window; grid steps
+    # run sequentially).  Offsets are SMEM scalar reads.
+    nt = nt_ref[...]
+    ps = ps_ref[...]
     for row in range(g):
         ci = i * g + row
-        fw = (nt_ref[row] + 7) // 8
-        pw = ps_ref[row]
-        fdst = jnp.minimum(sec_flags + fo_ref[ci], cap - cb)
-        cur = pl.load(out_ref, (slice(None), pl.dslice(fdst, cb)))
-        pl.store(
-            out_ref,
-            (slice(None), pl.dslice(fdst, cb)),
-            jnp.where(jf < fw, fbyte[row : row + 1, :], cur),
-        )
-        pdst = jnp.minimum(sec_flags + po_ref[ci], cap - bufsz)
-        cur = pl.load(out_ref, (slice(None), pl.dslice(pdst, bufsz)))
-        pl.store(
-            out_ref,
-            (slice(None), pl.dslice(pdst, bufsz)),
-            jnp.where(jp < pw, prow[row : row + 1, :], cur),
-        )
+        for win, dst, data, n in (
+            (
+                fwin,
+                sec_flags + fo_ref[ci],
+                fbyte[row : row + 1, :],
+                (nt[row : row + 1, :] + 7) // 8,
+            ),
+            (
+                pwin,
+                sec_flags + po_ref[ci],
+                prow[row : row + 1, :],
+                ps[row : row + 1, :],
+            ),
+        ):
+            r = dst % LANES
+            lanes = pl.ds(pl.multiple_of(dst - r, LANES), win.shape[1])
+            win[...] = out_ref[:, lanes]
+            _blend_window(win, data, r, n)
+            out_ref[:, lanes] = win[...]
 
 
 def _cost(nc, c, s):
@@ -272,6 +300,43 @@ def lz_scatter_pallas(
     ``deflate.scatter_section`` would have produced, with the header/table
     region [0, sec_flags) left zero for the caller to fill.
     """
+    return batch_by_loop(
+        functools.partial(
+            _scatter,
+            symbol_size=symbol_size,
+            cap=cap,
+            sec_flags=sec_flags,
+            chunks_per_block=chunks_per_block,
+            interpret=interpret,
+        )
+    )(
+        symbols,
+        lengths,
+        offsets,
+        emitted,
+        use_match,
+        local_off,
+        n_tokens,
+        payload_sizes,
+    )
+
+
+def _scatter(
+    symbols,
+    lengths,
+    offsets,
+    emitted,
+    use_match,
+    local_off,
+    n_tokens,
+    payload_sizes,
+    *,
+    symbol_size,
+    cap,
+    sec_flags,
+    chunks_per_block,
+    interpret,
+):
     fo, po, f_tot, p_tot = lz_global_offsets_pallas(
         n_tokens, payload_sizes, interpret=interpret
     )
@@ -294,27 +359,45 @@ def lz_scatter_pallas(
     emit = _pad_rows(emitted.astype(jnp.int32), pad)
     um = _pad_rows(use_match.astype(jnp.int32), pad)
     lo = _pad_rows(local_off.astype(jnp.int32), pad)
-    nt = _pad_rows(n_tokens.astype(jnp.int32), pad)
-    ps = _pad_rows(payload_sizes.astype(jnp.int32), pad)
+    nt = _pad_rows(n_tokens.astype(jnp.int32).reshape(-1, 1), pad)
+    ps = _pad_rows(payload_sizes.astype(jnp.int32).reshape(-1, 1), pad)
     npad = nc + pad
+    fl = flag_lanes(c)
+    bufp = _round_up(c * symbol_size, LANES)
+    # the whole blob is one VMEM-resident block, with room for the last
+    # window to overhang ``cap``
+    cap_alloc = _round_up(cap + bufp + 2 * LANES, LANES)
+    if 4 * cap_alloc > SCATTER_VMEM_BYTES:
+        raise ValueError(
+            f"lz_scatter keeps the whole container in VMEM: cap={cap} bytes "
+            f"needs {4 * cap_alloc / 2**20:.1f} MiB, over the "
+            f"{SCATTER_VMEM_BYTES / 2**20:.0f} MiB budget; use the fused-mono "
+            "compressor, which writes through HBM windows"
+        )
     spec2d = pl.BlockSpec((g, c), lambda i, fo_, po_: (i, 0))
-    spec1d = pl.BlockSpec((g,), lambda i, fo_, po_: (i,))
+    spec1d = pl.BlockSpec((g, 1), lambda i, fo_, po_: (i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(npad // g,),
         in_specs=[spec2d] * 6 + [spec1d] * 2,
-        out_specs=pl.BlockSpec((1, cap), lambda i, fo_, po_: (0, 0)),
+        out_specs=pl.BlockSpec((1, cap_alloc), lambda i, fo_, po_: (0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((1, fl + LANES), jnp.int32),
+            pltpu.VMEM((1, bufp + LANES), jnp.int32),
+        ],
     )
     out = pl.pallas_call(
         functools.partial(
             _scatter_kernel,
             symbol_size=symbol_size,
             sec_flags=sec_flags,
-            cap=cap,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, cap), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((1, cap_alloc), jnp.int32),
         cost_estimate=_cost(npad, c, symbol_size),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCATTER_VMEM_BYTES + 16 * 2**20
+        ),
         interpret=interpret,
     )(fo, po, sym, lens, offs, emit, um, lo, nt, ps)
-    return out[0], f_tot, p_tot
+    return out[0, :cap], f_tot, p_tot

@@ -230,6 +230,11 @@ def bitunshuffle(shuffled):
     return _bshuf_impl.bitunshuffle_pallas(shuffled, interpret=_interpret())
 
 
+def bitunshuffle_words(words):
+    """(N,) int32 little-endian byte pairs -> (N,) uint16 inverse transpose."""
+    return _bshuf_impl.bitunshuffle_words_pallas(words, interpret=_interpret())
+
+
 def lz_decode_mono(
     blob,
     n_tokens,

@@ -1,12 +1,12 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-TPU v5e-class hardware constants (per assignment):
-    197 TFLOP/s bf16 per chip | 819 GB/s HBM | ~50 GB/s/link ICI
-
 Three terms per (arch x shape x mesh), all in seconds-per-step:
     compute    = HLO_FLOPs / (chips x peak)
     memory     = HLO_bytes / (chips x hbm_bw)
     collective = collective_bytes / (chips x link_bw)
+
+The peaks come from ``DEVICE_PEAKS``, keyed by ``jax.Device.device_kind``;
+a kind missing from the table is an error, never a default.
 
 HLO_FLOPs / HLO_bytes come from ``compiled.cost_analysis()`` (per-device on
 the SPMD-partitioned module; x chips = global).  collective_bytes is parsed
@@ -19,9 +19,41 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s / chip
-LINK_BW = 50e9           # bytes/s / link
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    bf16_flops: float  # FLOP/s per chip
+    int8_ops: float  # OP/s per chip
+    hbm_bytes: float  # bytes of HBM per chip
+    hbm_bw: float  # bytes/s per chip
+    link_bw: float  # bytes/s per ICI link
+
+
+# Published per-chip peaks.  TPU v5e: Google Cloud documentation, "TPU
+# v5e" — 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600
+# Gbit/s of chip-to-chip interconnect over 4 links (50 GB/s each).
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12,
+        int8_ops=393e12,
+        hbm_bytes=16e9,
+        hbm_bw=819e9,
+        link_bw=1600e9 / 8 / 4,
+    ),
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"  # the production meshes' chip
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks for one ``device_kind``; raises for a kind not in the table."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(DEVICE_PEAKS)}"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -97,18 +129,23 @@ class Roofline:
     coll_bytes_per_device: float
     model_flops: float           # 6ND / 2ND analytic, global
     coll_breakdown: dict
+    device_kind: str = DRYRUN_DEVICE_KIND
+
+    @property
+    def peaks(self) -> DevicePeaks:
+        return device_peaks(self.device_kind)
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.bf16_flops
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes_per_device / LINK_BW
+        return self.coll_bytes_per_device / self.peaks.link_bw
 
     @property
     def dominant(self) -> str:
@@ -135,7 +172,7 @@ class Roofline:
         t = self.bound_s
         if t <= 0:
             return 0.0
-        return self.model_flops / (self.chips * PEAK_FLOPS * t)
+        return self.model_flops / (self.chips * self.peaks.bf16_flops * t)
 
     def row(self) -> dict:
         return {

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro import configs
 from repro.launch import steps
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.engine import ServingEngine
 
 
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--kv-compress", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch)
     if args.reduced:

@@ -18,7 +18,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.models import model as model_lib, transformer
 from repro.optim import adamw, grad_compress
@@ -214,11 +214,13 @@ def fsdp_decision(cfg, traincfg) -> bool:
 
 
 def _set_mesh_context(mesh, batch_axes=None, fsdp=True, seq_parallel=False):
-    """Install the mesh so bare-PartitionSpec sharding hints resolve."""
-    try:
-        jax.sharding.set_mesh(mesh)
-    except Exception:
-        pass
+    """Set the sharding rules up for ``mesh``; returns its Auto-axis view.
+
+    The step builders use the Auto view: the sharding rules pin arrays
+    through constraints and let the partitioner propagate the rest,
+    whatever axis types the caller's mesh carries.
+    """
+    mesh = mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     if batch_axes is None:
         batch_axes = rules.batch_axes(mesh)
     shards = 1
@@ -228,13 +230,27 @@ def _set_mesh_context(mesh, batch_axes=None, fsdp=True, seq_parallel=False):
     rules.set_activation_batch_axes(batch_axes, data_shards=shards)
     rules.set_fsdp(fsdp)
     rules.set_seq_parallel(seq_parallel)
+    return mesh
+
+
+def _in_mesh(mesh, fn):
+    """Trace ``fn`` with ``mesh`` as the context mesh, so bare-PartitionSpec
+    sharding hints resolve against it; nothing process-global is set, so
+    other code (a sharded codec dispatch over its own mesh) is unaffected."""
+
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+
+    return run
 
 
 def make_train_step(cfg, traincfg, mesh, shape, compressed: bool = False):
     """Returns (jitted_fn, state_shardings, batch_shardings)."""
     # compressed step vmaps over the pod axis (spmd_axis_name supplies it);
     # inner activation constraints then use "data" only.
-    _set_mesh_context(
+    mesh = _set_mesh_context(
         mesh,
         batch_axes=("data",) if compressed else None,
         fsdp=fsdp_decision(cfg, traincfg),
@@ -246,12 +262,13 @@ def make_train_step(cfg, traincfg, mesh, shape, compressed: bool = False):
         fn = functools.partial(
             train_step_compressed, cfg=cfg, traincfg=traincfg, mesh=mesh
         )
+        fn = _in_mesh(mesh, fn)
         # shard_map handles its own specs; jit still pins the boundary
         jfn = jax.jit(
             fn, in_shardings=(st_sh, b_sh), out_shardings=(st_sh, None)
         )
     else:
-        fn = functools.partial(train_step, cfg=cfg, traincfg=traincfg)
+        fn = _in_mesh(mesh, functools.partial(train_step, cfg=cfg, traincfg=traincfg))
         jfn = jax.jit(
             fn,
             in_shardings=(st_sh, b_sh),
@@ -280,7 +297,7 @@ def cache_shardings(cfg, batch_size, mesh):
 
 
 def make_decode_step(cfg, mesh, shape):
-    _set_mesh_context(mesh)
+    mesh = _set_mesh_context(mesh)
     b = shape.global_batch
     ab_cache = model_lib.abstract_cache(cfg, b, shape.seq_len)
     sh_fn = cache_shardings(cfg, b, mesh)
@@ -291,7 +308,7 @@ def make_decode_step(cfg, mesh, shape):
         "tokens": NamedSharding(mesh, bs),
         "pos": NamedSharding(mesh, P()),
     }
-    fn = functools.partial(decode_step, cfg=cfg)
+    fn = _in_mesh(mesh, functools.partial(decode_step, cfg=cfg))
     jfn = jax.jit(
         fn,
         in_shardings=(p_sh, cache_sh, b_sh),
@@ -309,7 +326,7 @@ def make_paged_decode_step(cfg, mesh, shape, *, block_tokens,
     the dense cache); block tables are tiny int32 host-authored state and
     stay replicated.  Paged state is donated so the pool updates in place.
     """
-    _set_mesh_context(mesh)
+    mesh = _set_mesh_context(mesh)
     b = shape.global_batch
     ab = model_lib.abstract_paged_cache(
         cfg, b, shape.seq_len, block_tokens=block_tokens,
@@ -328,7 +345,7 @@ def make_paged_decode_step(cfg, mesh, shape, *, block_tokens,
         "tokens": NamedSharding(mesh, bs),
         "pos": NamedSharding(mesh, P()),
     }
-    fn = functools.partial(paged_decode_step, cfg=cfg)
+    fn = _in_mesh(mesh, functools.partial(paged_decode_step, cfg=cfg))
     jfn = jax.jit(
         fn,
         in_shardings=(p_sh, paged_sh, b_sh),
@@ -339,13 +356,13 @@ def make_paged_decode_step(cfg, mesh, shape, *, block_tokens,
 
 
 def make_prefill_step(cfg, mesh, shape, unroll=False):
-    _set_mesh_context(mesh)
+    mesh = _set_mesh_context(mesh)
     p_sh = rules.params_shardings(model_lib.param_axes(cfg), mesh)
     b_sh = batch_shardings(cfg, shape, mesh)
     specs = {"layers": rules.compute_specs_tree(
         model_lib.param_axes(cfg)["layers"], drop_leading=1)}
-    fn = functools.partial(prefill_step, cfg=cfg, unroll=unroll,
-                           compute_specs=specs)
+    fn = _in_mesh(mesh, functools.partial(prefill_step, cfg=cfg, unroll=unroll,
+                                          compute_specs=specs))
     logits_sh = NamedSharding(mesh, rules.batch_spec(mesh, shape.global_batch))
     jfn = jax.jit(fn, in_shardings=(p_sh, b_sh), out_shardings=logits_sh)
     return jfn, p_sh, b_sh

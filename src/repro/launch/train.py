@@ -27,6 +27,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import CompressionConfig, ShapeConfig, TrainConfig
 from repro.data.pipeline import DataConfig, Prefetcher
 from repro.launch import mesh as mesh_lib, steps
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault import StepGuard
 
 
@@ -58,6 +59,7 @@ def build(args):
 
 
 def train_loop(args):
+    """Train ``args.steps`` steps; returns ``(losses, final train state)``."""
     cfg, traincfg, mesh, shape = build(args)
     jfn, st_sh, b_sh = steps.make_train_step(cfg, traincfg, mesh, shape)
     mgr = CheckpointManager(
@@ -130,7 +132,7 @@ def train_loop(args):
         f"[train] loss {losses[0]:.4f} -> {losses[-1]:.4f} over "
         f"{len(losses)} steps"
     )
-    return np.array(losses)
+    return np.array(losses), state
 
 
 def main():
@@ -154,6 +156,7 @@ def main():
     ap.add_argument("--heartbeat", default="")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
     train_loop(args)
 
 

@@ -183,9 +183,21 @@ def attention_decode(params, cfg, cache, x, pos, is_global=True):
 
     x: (B, 1, d); pos: scalar int32 (current absolute position).
     """
-    cache_len = cache["k"].shape[1]
     q, k, v = _decode_qkv(params, cfg, x, pos)  # k stored post-rope
+    ck, cv, keep, new_cache = decode_cache_write(cfg, cache, k, v, pos, is_global)
+    return decode_attend_out(params, q, ck, cv, keep, x.dtype), new_cache
 
+
+def decode_cache_write(cfg, cache, k, v, pos, is_global=True):
+    """Dense-cache half of ``attention_decode``: store the new token's k/v.
+
+    Returns the attended view (ck, cv), its (B, 1, 1, S) keep mask and the
+    new cache.  Pure data movement (plus the int8 dequantize when
+    ``kv_quant``): the serving engine launches it between the shared
+    ``_decode_qkv`` and ``decode_attend_out`` launches, so the dense and
+    paged tiers differ only in exact moves.
+    """
+    cache_len = cache["k"].shape[1]
     slot = pos % cache_len  # ring buffer (identity when cache covers all pos)
     new_cache = {}
     if cfg.kv_quant:
@@ -198,8 +210,8 @@ def attention_decode(params, cfg, cache, x, pos, is_global=True):
         cvs = lax.dynamic_update_slice_in_dim(cache["v_scale"], vs, slot,
                                               axis=1)
         new_cache.update(k=ckq, v=cvq, k_scale=cks, v_scale=cvs)
-        ck = _dequantize_kv(ckq, cks, x.dtype)
-        cv = _dequantize_kv(cvq, cvs, x.dtype)
+        ck = _dequantize_kv(ckq, cks, k.dtype)
+        cv = _dequantize_kv(cvq, cvs, v.dtype)
     else:
         ck = lax.dynamic_update_slice_in_dim(cache["k"], k, slot, axis=1)
         cv = lax.dynamic_update_slice_in_dim(cache["v"], v, slot, axis=1)
@@ -213,9 +225,14 @@ def attention_decode(params, cfg, cache, x, pos, is_global=True):
     if cfg.sliding_window:
         in_win = (pos - spos) < cfg.sliding_window
         valid = valid & (is_global | in_win)
-    out = _decode_attend(q, ck, cv, valid[None, None, None], x.dtype)
-    y = jnp.einsum("bthk,hkd->btd", out, params["wo"])
-    return y, new_cache
+    keep = jnp.broadcast_to(valid, (k.shape[0], 1, 1, cache_len))
+    return ck, cv, keep, new_cache
+
+
+def decode_attend_out(params, q, ck, cv, keep, out_dtype):
+    """Attention over a cache view, then the output projection."""
+    out = _decode_attend(q, ck, cv, keep, out_dtype)
+    return jnp.einsum("bthk,hkd->btd", out, params["wo"])
 
 
 # --------------------------------------------------------------- paged GQA
@@ -249,12 +266,18 @@ def paged_attention_decode(params, cfg, pool, table, x, pos, is_global=True):
     future slots mask to exactly zero probability, so stale pool contents
     never reach the output (decode_attend masks pre-softmax at NEG_INF).
     """
-    b = x.shape[0]
+    q, k, v = _decode_qkv(params, cfg, x, pos)  # k stored post-rope
+    ck, cv, keep, pool = paged_cache_write(cfg, pool, table, k, v, pos, is_global)
+    return decode_attend_out(params, q, ck, cv, keep, x.dtype), pool
+
+
+def paged_cache_write(cfg, pool, table, k, v, pos, is_global=True):
+    """Paged twin of ``decode_cache_write``: store the new token's k/v in
+    its slot and gather the logical view through the block table."""
+    b = k.shape[0]
     bt = pool["k"].shape[1]
     n_logical = table.shape[1]
     kvh, dh = pool["k"].shape[2], pool["k"].shape[3]
-    q, k, v = _decode_qkv(params, cfg, x, pos)  # k stored post-rope
-
     phys = table[jnp.arange(b), pos // bt]
     kp = pool["k"].at[phys, pos % bt].set(k[:, 0])
     vp = pool["v"].at[phys, pos % bt].set(v[:, 0])
@@ -267,9 +290,7 @@ def paged_attention_decode(params, cfg, pool, table, x, pos, is_global=True):
     if cfg.sliding_window:
         in_win = (pos - t_idx) < cfg.sliding_window
         valid = valid & (is_global | in_win[None])
-    out = _decode_attend(q, ck, cv, valid[:, None, None, :], x.dtype)
-    y = jnp.einsum("bthk,hkd->btd", out, params["wo"])
-    return y, {"k": kp, "v": vp}
+    return ck, cv, valid[:, None, None, :], {"k": kp, "v": vp}
 
 
 # --------------------------------------------------------------------- MLA

@@ -297,6 +297,24 @@ def decode_layer(lp, cfg, c, x, pos, is_global):
     return _decode_tail(lp, cfg, x), nc
 
 
+def decode_layer_qkv(lp, cfg, x, pos):
+    """Pre-norm and q/k/v of an attention layer (k post-rope).
+
+    The serving engine runs an attention layer as three launches, this, a
+    cache write that only moves data (``attention.decode_cache_write`` or
+    ``paged_cache_write``) and ``decode_layer_attend``, so its dense and
+    paged tiers share every launch that computes.
+    """
+    h = common.rms_norm(x, lp["ln1"], cfg.norm_eps)
+    return attention._decode_qkv(lp["attn"], cfg, h, pos)
+
+
+def decode_layer_attend(lp, cfg, x, q, ck, cv, keep):
+    """Attention over a written cache view, its residual, then the FFN."""
+    out = attention.decode_attend_out(lp["attn"], q, ck, cv, keep, x.dtype)
+    return _decode_tail(lp, cfg, x + out)
+
+
 def decode_finish(params, cfg, x):
     """Final norm + unembed of a decode step -> (B, V) logits."""
     h = common.rms_norm(x, params["ln_f"], cfg.norm_eps)

@@ -321,12 +321,21 @@ def pod_exchange_compressed(grad_stack, mesh, compress: bool = True,
             mesh, "pod",
         )(g)
         wire = jax.tree.map(rep, wire)  # <- compressed pod all-gather
-        acc = 0.0
-        for k in range(n_pods):
-            wk = jax.tree.map(lambda x: x[k], wire)
-            acc = acc + decompress_leaf(
-                wk, shape, local_cfg, ratio_cap, lossy_eb
-            )
-        return (acc / n_pods).astype(g.dtype)
+
+        def average(wire):
+            acc = 0.0
+            for k in range(n_pods):
+                wk = jax.tree.map(lambda x: x[k], wire)
+                acc = acc + decompress_leaf(
+                    wk, shape, local_cfg, ratio_cap, lossy_eb
+                )
+            return (acc / n_pods).astype(g.dtype)
+
+        # every device decodes its full replica of the wire; the explicit
+        # map keeps the partitioner from splitting the decode, which it
+        # cannot do for a Mosaic kernel
+        return jax.shard_map(
+            average, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
+        )(wire)
 
     return jax.tree.map(exchange_leaf, grad_stack)

@@ -13,12 +13,14 @@ models/transformer.py.  Two KV tiers:
   slot, with a prefetch queue restoring predicted-hot blocks (next access
   group in the layer-major sequence) ahead of demand.
 
-The tier is *layer-streaming*: each decode step launches one jitted graph
-per layer, so only the current layer's block working set must be resident
-and the budget can sit well below the all-layers working set while staying
-exact.  Both tiers drive the SAME per-layer launch granularity — XLA rounds
-bf16 intermediates at jit boundaries, so equal granularity makes generated
-tokens bit-identical between them (EXPERIMENTS.md §Serving).
+The tier is *layer-streaming*: each decode step launches the jitted graphs
+of one layer at a time, so only the current layer's block working set must
+be resident and the budget can sit well below the all-layers working set
+while staying exact.  Both tiers drive the SAME per-layer launches — XLA
+rounds bf16 intermediates at jit boundaries, so equal granularity makes
+generated tokens bit-identical between them (EXPERIMENTS.md §Serving).  An
+attention layer is three launches (q/k/v, cache write, attend+FFN) of which
+only the data-moving middle one differs between the tiers.
 
 With ``async_prefetch=True`` the prefetch restore runs on a background
 worker: ``_drain_prefetch`` allocates target slots on the main thread,
@@ -128,6 +130,33 @@ class ServingEngine:
                     lp, cfg, pool, table, extra, x, pos, g
                 ),
             static_argnums=(6,), donate_argnums=(1,),
+        )
+        # an attention layer runs as three launches on both tiers: shared
+        # q/k/v and attend+FFN graphs around a tier-specific cache write
+        # that only moves data.  So the tiers share every launch that
+        # computes, and their tokens agree bit for bit on any backend (one
+        # graph per layer and tier agreed on the CPU, not on a TPU, whose
+        # compiler fuses the two tiers' attention differently)
+        self._split = cfg.mixer == "attention"
+        self._qkv = jax.jit(
+            lambda lp, x, pos: transformer.decode_layer_qkv(lp, cfg, x, pos)
+        )
+        self._attend = jax.jit(
+            lambda lp, x, q, ck, cv, keep: transformer.decode_layer_attend(
+                lp, cfg, x, q, ck, cv, keep
+            )
+        )
+        self._cache_write = jax.jit(
+            lambda c, k, v, pos, g: attention.decode_cache_write(
+                cfg, c, k, v, pos, g
+            ),
+            static_argnums=(4,), donate_argnums=(0,),
+        )
+        self._paged_write = jax.jit(
+            lambda pool, table, k, v, pos, g: attention.paged_cache_write(
+                cfg, pool, table, k, v, pos, g
+            ),
+            static_argnums=(5,), donate_argnums=(0,),
         )
         # KVBlockStore round-trips flat uint8; the engine owns the real
         # dtype (np.dtype(bf16).str is lossy '<V2', so (dtype.str, shape)
@@ -444,22 +473,33 @@ class ServingEngine:
             posj = jnp.int32(pos)
             x = self._embed(self.params, toks)
             for i in range(self.cfg.num_layers):
+                lp, g = self._layer_params[i], self._is_global[i]
                 if paged:
                     self._ensure_resident(i, pos)
-                    x, self._pool, self._extra[i] = self._paged_layer_step(
-                        self._layer_params[i], self._pool,
-                        jnp.asarray(self._tables[i]), self._extra[i],
-                        x, posj, self._is_global[i],
-                    )
+                    table = jnp.asarray(self._tables[i])
+                    if self._split:
+                        q, k, v = self._qkv(lp, x, posj)
+                        ck, cv, keep, self._pool = self._paged_write(
+                            self._pool, table, k, v, posj, g
+                        )
+                        x = self._attend(lp, x, q, ck, cv, keep)
+                    else:
+                        x, self._pool, self._extra[i] = self._paged_layer_step(
+                            lp, self._pool, table, self._extra[i], x, posj, g
+                        )
                     assert self._alloc.allocated <= self.budget_blocks
                     if self.kv_prefetch:
                         self._push_prefetch(i, pos)
                         self._drain_prefetch(i, pos)
-                else:
-                    x, caches[i] = self._layer_step(
-                        self._layer_params[i], caches[i], x, posj,
-                        self._is_global[i],
+                elif self._split:
+                    q, k, v = self._qkv(lp, x, posj)
+                    ck, cv, keep, c = self._cache_write(
+                        caches[i]["attn"], k, v, posj, g
                     )
+                    caches[i] = {"attn": c}
+                    x = self._attend(lp, x, q, ck, cv, keep)
+                else:
+                    x, caches[i] = self._layer_step(lp, caches[i], x, posj, g)
             logits = self._finish(self.params, x)
             n_steps += 1
             if pos + 1 < tp:

@@ -41,8 +41,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.core import pipeline
 from repro.sharding import rules
@@ -91,37 +90,21 @@ def normalize_batch_axes(mesh: Mesh, batch_axis=None) -> tuple:
 def _sharded_call(fn, mesh: Mesh, axes: tuple, in_arity: int):
     """shard_map ``fn`` with dim 0 of every arg and output split over ``axes``.
 
-    Mesh axes outside ``axes`` replicate the computation, and their outputs
-    are gathered by explicit *untiling*: the body prepends one length-1 dim
-    per unmentioned axis so ``out_specs`` can name every mesh axis, and
-    replica 0 is sliced off afterwards.  Simply omitting an axis from
-    ``out_specs`` under ``check_rep=False`` is not portable: eager shard_map
-    returns one replica, but inside jit the partitioner may *sum* the
-    replicas instead (observed on forced-host CPU meshes), which corrupts
-    byte-exact output.  ``check_rep=False`` itself is required because the
-    body runs jitted pipeline code (Pallas kernels on TPU) whose replication
-    XLA cannot infer.
+    Mesh axes outside ``axes`` replicate the computation; every replica
+    holds the same bytes, so the output names only ``axes``.
+    ``check_vma=False`` is required because the body runs jitted pipeline
+    code (Pallas kernels on TPU) whose replication JAX cannot infer.  The
+    map runs over Auto axes whatever the caller's mesh says, so the ragged
+    trims after it (``blobs[:b]``) need no sharding annotations.
     """
-    other = tuple(a for a in mesh.axis_names if a not in axes)
-    k = len(other)
-
-    def body(*args):
-        out = fn(*args)
-        return jax.tree.map(lambda x: x.reshape((1,) * k + x.shape), out)
-
-    run = shard_map(
-        body,
-        mesh=mesh,
+    auto = mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    return jax.shard_map(
+        fn,
+        mesh=auto,
         in_specs=(P(axes),) * in_arity,
-        out_specs=P(*other, axes),
-        check_rep=False,
+        out_specs=P(axes),
+        check_vma=False,
     )
-
-    def call(*args):
-        out = run(*args)
-        return jax.tree.map(lambda x: x[(0,) * k], out)
-
-    return call
 
 
 def shard_vmap(fn, mesh: Mesh, axis):
@@ -146,11 +129,7 @@ def _pad_rows(x: jnp.ndarray, rows: int) -> jnp.ndarray:
 
     Zero rows are valid pipeline inputs on both sides (all-zero symbols
     compress fine; a zero "container" row decodes as zero tokens — every
-    section gather is bounds-checked).  Constant padding specifically:
-    gather-based row padding (``mode="edge"`` / ``jnp.concatenate`` of a
-    broadcast last row) feeding a shard_map whose mesh has unmentioned axes
-    miscompiles under jit on CPU — the partitioner sums the replicas of the
-    padded operand across the unmentioned axis, corrupting the bytes.
+    section gather is bounds-checked).
     """
     pad = rows - x.shape[0]
     if pad == 0:

@@ -146,8 +146,8 @@ def activation_spec(mesh: Mesh, batch_size: int) -> P:
 
 # --------------------------------------------------------------------------
 # Activation-sharding context: model code pins batch sharding with bare
-# PartitionSpecs (resolved against the mesh installed by jax.sharding.set_mesh
-# in the step builders).  Without these pins the SPMD partitioner may choose
+# PartitionSpecs (resolved against the context mesh the step builders in
+# launch/steps.py trace under).  Without these pins the SPMD partitioner may choose
 # to replicate activations instead of weights once weights are FSDP-sharded.
 
 _BATCH_AXES: tuple = ("data",)
@@ -191,12 +191,11 @@ def constrain_batch(x, *rest):
     sequence parallelism on, 3D activations additionally shard dim1 (T) on
     the model axis.
     """
+    if jax.sharding.get_abstract_mesh().empty:
+        return x
     explicit = len(rest)
     rest = list(rest) + [None] * (x.ndim - 1 - len(rest))
     if _SEQ_PARALLEL and explicit == 0 and x.ndim == 3:
         rest[0] = "model"
     spec = [_BATCH_AXES] + rest
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except (RuntimeError, ValueError, TypeError):
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))

@@ -99,3 +99,36 @@ def test_save_batches_dtype_classes(tmp_path, state, monkeypatch):
     assert step == 1
     for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(restored)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_restore_latest_skips_truncated_container(tmp_path, state):
+    """A container that fails validation (ValueError) is damaged data:
+    restore_latest falls back to the previous step."""
+    mgr = CheckpointManager(str(tmp_path), compress=True, keep=5)
+    mgr.save(state, 1)
+    mgr.save(state, 2)
+    blob = max(
+        glob.glob(os.path.join(str(tmp_path), "step_00000002", "*.gplz")),
+        key=os.path.getsize,
+    )
+    with open(blob, "r+b") as f:
+        f.truncate(os.path.getsize(blob) // 2)
+    restored, step = mgr.restore_latest(jax.eval_shape(lambda: state))
+    assert step == 1
+
+
+def test_restore_latest_propagates_decoder_failure(tmp_path, state, monkeypatch):
+    """A decoder that fails for a reason other than damaged data (say a
+    kernel that cannot compile on the device) must surface, not read as
+    "no checkpoint" and silently restart training from step 0."""
+    from repro.core import lzss
+
+    mgr = CheckpointManager(str(tmp_path), compress=True)
+    mgr.save(state, 1)
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(lzss, "decompress_many", broken)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        mgr.restore_latest(jax.eval_shape(lambda: state))

@@ -353,7 +353,7 @@ def test_autotune_sweep_runs_eagerly_from_host_entry_points(tuned_env, monkeypat
         m = real(key)
 
         def measure(c, g):
-            states.append(jax.core.trace_state_clean())
+            states.append(autotune.trace_state_clean())
             return m(c, g)
 
         return measure

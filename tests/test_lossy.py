@@ -20,11 +20,13 @@ The hypothesis twin of the bound property lives in tests/test_properties.py
 (optional extra); THIS file is what always runs in the CI ``lossy`` lane.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import test_conformance as conf  # same-dir pytest import
-from repro.core import bitshuffle, format as fmt, lzss, pipeline
+from repro.core import bitshuffle, format as fmt, lossy, lzss, pipeline
 
 EB_SWEEP = [1e-2, 1e-4]
 
@@ -77,6 +79,11 @@ def test_config_validation():
         lzss.LZSSConfig(symbol_size=4, backend="lossy-fz", lossy_eb=np.inf)
     with pytest.raises(ValueError, match="f32"):
         lzss.LZSSConfig(symbol_size=2, backend="lossy-fz", lossy_eb=1e-3)
+    for eb in (2.0**-128, 2.0**125, 1e38):
+        with pytest.raises(ValueError, match="2\\*\\*-127"):
+            lzss.LZSSConfig(symbol_size=4, backend="lossy-fz", lossy_eb=eb)
+    for eb in (2.0**-127, 1e37, 1e-46):  # 1e-46 rounds to f32 0: lossless
+        lzss.LZSSConfig(symbol_size=4, backend="lossy-fz", lossy_eb=eb)
     with pytest.raises(ValueError, match="lossy_eb is only consulted"):
         lzss.LZSSConfig(symbol_size=4, backend="xla", lossy_eb=1e-3)
     with pytest.raises(ValueError, match="not a lossless"):
@@ -331,6 +338,38 @@ def test_bitshuffle_roundtrip_and_pallas_parity():
         bitshuffle.shuffle(units[:100])
     with pytest.raises(ValueError, match="impl"):
         bitshuffle.shuffle(units, impl="cuda")
+
+
+def test_prequant_scale_is_the_ieee_reciprocal():
+    """The pre-quant scale is fl32(1/eb2) on every backend: integer long
+    division, not the platform's divide (TPU's is not correctly rounded,
+    and an ulp between encoder and decoder mis-anchors the delta chain)."""
+    rng = np.random.default_rng(4)
+    lo, hi = 1 << 23, 253 << 23  # normal eb2 below 2**126
+    bits = rng.integers(lo, hi, 200_000, dtype=np.int64).astype(np.int32)
+    edges = np.arange(1, 253, dtype=np.int32) << 23  # powers of two
+    bits = np.concatenate([bits, edges, edges | 1, edges | 0x7FFFFF])
+    eb2 = bits.view(np.float32)
+    got = np.asarray(jax.jit(jax.vmap(lossy._rcp))(jnp.asarray(eb2)))
+    np.testing.assert_array_equal(
+        got.view(np.int32), (np.float32(1) / eb2).view(np.int32)
+    )
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_bitunshuffle_words_inverts_shuffle(impl):
+    """The decoder's form: shuffled bytes as little-endian pairs."""
+    rng = np.random.default_rng(3)
+    units = rng.integers(0, 1 << 16, 3 * bitshuffle.BLOCK_UNITS).astype(
+        np.uint16
+    )
+    b = np.asarray(bitshuffle.shuffle(units, impl="xla")).astype(np.int32)
+    words = b[0::2] | (b[1::2] << 8)
+    np.testing.assert_array_equal(
+        np.asarray(bitshuffle.unshuffle_words(words, impl=impl)), units
+    )
+    with pytest.raises(ValueError, match="multiple"):
+        bitshuffle.unshuffle_words(words[:100], impl=impl)
 
 
 # ------------------------------------------------------------ consumers

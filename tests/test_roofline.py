@@ -63,3 +63,22 @@ def test_moe_uses_active_params():
     cfg = configs.get_config("deepseek-v2-236b")
     tr = roofline.model_flops_for(cfg, configs.get_shape("train_4k"))
     assert tr < 6.0 * cfg.param_count() * 256 * 4096 * 0.2
+
+
+def test_peaks_keyed_by_device_kind():
+    p = roofline.device_peaks("TPU v5 lite")
+    assert p.bf16_flops == 197e12 and p.hbm_bw == 819e9
+
+
+def test_peaks_unknown_device_kind_raises():
+    import pytest
+
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.device_peaks("TPU v99")
+    rl = roofline.Roofline(
+        arch="a", shape="s", mesh="1", chips=1, flops_per_device=1.0,
+        bytes_per_device=1.0, coll_bytes_per_device=0.0, model_flops=1.0,
+        coll_breakdown={}, device_kind="cpu",
+    )
+    with pytest.raises(ValueError):
+        rl.compute_s
