@@ -1,0 +1,12 @@
+"""Share of the compress calls' device busy time outside Mosaic kernels (%).
+
+Busy time of operations that are not ``tpu_custom_call`` kernels, over all
+device busy time, inside the ``bench.compress`` spans: the pipeline glue
+around the kernels. Read in the resident cells; moves ``compress_GBps``.
+"""
+
+from bench import trace
+
+
+def read(ctx):
+    return trace.xla_share(ctx, "compress")
