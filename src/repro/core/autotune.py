@@ -91,7 +91,6 @@ CHUNKS_PER_BLOCK_CANDIDATES = (8, 16, 32)
 FALLBACK_TABLE: Dict[Tuple[str, str], Tuple[int, int]] = {}
 
 _MEMO: Dict[str, Tuple[int, int]] = {}  # per-process: cache_key -> (C, g)
-_SWEEPS: Dict[str, int] = {}  # telemetry (tests assert on it): key -> sweeps
 
 
 @dataclasses.dataclass(frozen=True)
@@ -313,7 +312,6 @@ def _store_cache(path: str, cache: dict) -> None:
 def reset() -> None:
     """Drop per-process memoized geometry (tests / env changes)."""
     _MEMO.clear()
-    _SWEEPS.clear()
 
 
 # --------------------------------------------------------------- tuning
@@ -448,7 +446,6 @@ def best_geometry(
         measure = _default_measure(key)
     cands = candidates(key)
     timed = [(measure(c, g), c, g) for c, g in cands]
-    _SWEEPS[ck] = _SWEEPS.get(ck, 0) + 1
     best_t, c, g = min(timed)
     cache["entries"][ck] = {
         "chunk_symbols": c,

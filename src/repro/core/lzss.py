@@ -18,10 +18,12 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import format as fmt
+from repro.runtime import tracing
 
 # The jittable pipeline layer; re-exported so existing imports keep working.
 from repro.core.pipeline import (  # noqa: F401
@@ -84,17 +86,18 @@ def _dispatch_capacity(n_bytes: int) -> int:
 _SYMBOL_DTYPE = {1: np.dtype("u1"), 2: np.dtype("<u2"), 4: np.dtype("<i4")}
 
 
-def _pack_padded(raw: np.ndarray, nc: int, cfg: LZSSConfig) -> jnp.ndarray:
-    """(n,) uint8 host bytes -> (nc, C) int32 symbols, zero-padded.
+def _pack_padded(raw: np.ndarray, nc: int, cfg: LZSSConfig) -> np.ndarray:
+    """(n,) uint8 host bytes -> (nc, C) int32 host symbols, zero-padded.
 
     Packed on the host as a dtype view: the device receives the int32
     symbols alone, never an (n, S) byte intermediate.
     """
     s, c = cfg.symbol_size, cfg.chunk_symbols
-    padded = np.zeros(nc * c * s, np.uint8)
-    padded[: raw.size] = raw
-    sym = padded.view(_SYMBOL_DTYPE[s]).astype(np.int32)
-    return jnp.asarray(sym.reshape(nc, c))
+    with tracing.span("lzss.compress.pack"):
+        padded = np.zeros(nc * c * s, np.uint8)
+        padded[: raw.size] = raw
+        sym = padded.view(_SYMBOL_DTYPE[s]).astype(np.int32)
+        return sym.reshape(nc, c)
 
 
 def _as_bytes(data) -> np.ndarray:
@@ -103,17 +106,30 @@ def _as_bytes(data) -> np.ndarray:
 
 def compress(data, config: LZSSConfig = DEFAULT_CONFIG) -> CompressResult:
     """Compress any array/bytes. Pads to whole chunks; header records truth."""
-    raw = _as_bytes(data)
-    n = raw.size
-    s, c = config.symbol_size, config.chunk_symbols
-    nsym = -(-max(n, 1) // s)
-    nc = -(-nsym // c)
-    symbols = _pack_padded(raw, nc, config)
-    # tuned geometry must resolve HERE, outside the jit trace — timed
-    # sweeps inside a trace would measure tracing, not kernels
-    config = resolve_chunk_geometry(config)
-    buf, total = compress_chunks(symbols, config, jnp.int32(n))
-    buf, total = np.asarray(buf), int(total)
+    with tracing.span("lzss.compress.pack"):
+        raw = _as_bytes(data)
+        n = raw.size
+        s, c = config.symbol_size, config.chunk_symbols
+        nsym = -(-max(n, 1) // s)
+        nc = -(-nsym // c)
+    sym = _pack_padded(raw, nc, config)
+    with tracing.span("lzss.compress.upload"):
+        # jnp.asarray returns before the copy ends: wait here, so that link
+        # time is upload's and not the device's wait
+        symbols = jax.block_until_ready(jnp.asarray(sym))
+    with tracing.span("lzss.compress.dispatch"):
+        # tuned geometry must resolve HERE, outside the jit trace — timed
+        # sweeps inside a trace would measure tracing, not kernels
+        config = resolve_chunk_geometry(config)
+        buf, total = compress_chunks(symbols, config, jnp.int32(n))
+    with tracing.span("lzss.compress.wait"):
+        jax.block_until_ready((buf, total))
+    with tracing.span("lzss.compress.download"):
+        d2h = buf.nbytes + total.nbytes
+        buf, total = np.asarray(buf), int(total)
+    tracing.count("lzss.compress.bytes", n)
+    tracing.count("lzss.compress.h2d_bytes", symbols.nbytes)
+    tracing.count("lzss.compress.d2h_bytes", d2h)
     return CompressResult(data=buf[:total], orig_bytes=n, total_bytes=total)
 
 
@@ -127,12 +143,14 @@ def decompress(blob, decoder: str = "auto", chunks_per_block=None) -> np.ndarray
     the decode kernels' block geometry (format-invisible; ``None`` = the
     autotuner, resolved eagerly here — outside the jit trace).
     """
-    blob = np.asarray(blob, np.uint8)
-    # raises ValueError (expected vs actual byte counts) on truncated or
-    # table-corrupted blobs instead of decoding garbage symbols
-    h, n_tokens, payload_sizes = fmt.validate_container(blob)
-    full = np.zeros(_dispatch_capacity(blob.size), np.uint8)
-    full[: blob.size] = blob
+    with tracing.span("lzss.decompress.validate"):
+        blob = np.asarray(blob, np.uint8)
+        # raises ValueError (expected vs actual byte counts) on truncated or
+        # table-corrupted blobs instead of decoding garbage symbols
+        h, n_tokens, payload_sizes = fmt.validate_container(blob)
+    with tracing.span("lzss.decompress.stage"):
+        full = np.zeros(_dispatch_capacity(blob.size), np.uint8)
+        full[: blob.size] = blob
     # the container's method byte routes the decode: entropy containers
     # decode only through the entropy decoder, lossy ones only through the
     # lossy decoder, raw ones through any raw decoder — a mismatch is a
@@ -169,25 +187,41 @@ def decompress(blob, decoder: str = "auto", chunks_per_block=None) -> np.ndarray
                 "decoder='lossy-fz' decodes method-2 (lossy) containers "
                 f"only; this container's method byte is {h.method}"
             )
-    symbols = decompress_chunks(
-        jnp.asarray(full),
-        jnp.asarray(n_tokens),
-        jnp.asarray(payload_sizes),
-        symbol_size=h.symbol_size,
-        chunk_symbols=h.chunk_symbols,
-        n_chunks=h.n_chunks,
-        decoder=dec,
+    with tracing.span("lzss.decompress.upload"):
+        args = jax.block_until_ready(
+            (jnp.asarray(full), jnp.asarray(n_tokens), jnp.asarray(payload_sizes)))
+    with tracing.span("lzss.decompress.dispatch"):
         # tuned decode geometry resolves eagerly — never inside the trace
-        chunks_per_block=resolve_decode_geometry(
+        cpb = resolve_decode_geometry(
             chunks_per_block,
             symbol_size=h.symbol_size,
             chunk_symbols=h.chunk_symbols,
             decoder=dec,
-        ),
-        method_params=method_params,
-    )
-    sym = np.asarray(symbols).reshape(-1).astype(_SYMBOL_DTYPE[h.symbol_size])
-    return sym.view(np.uint8)[: h.orig_bytes]
+        )
+        # one decode program per distinct key: what host set-up compiles
+        tracing.count_distinct("lzss.decompress.programs", (
+            full.size, h.symbol_size, h.chunk_symbols, h.n_chunks, dec, cpb,
+            method_params))
+        symbols = decompress_chunks(
+            *args,
+            symbol_size=h.symbol_size,
+            chunk_symbols=h.chunk_symbols,
+            n_chunks=h.n_chunks,
+            decoder=dec,
+            chunks_per_block=cpb,
+            method_params=method_params,
+        )
+    with tracing.span("lzss.decompress.wait"):
+        jax.block_until_ready(symbols)
+    with tracing.span("lzss.decompress.download"):
+        host = np.asarray(symbols)
+    with tracing.span("lzss.decompress.unpack"):
+        sym = host.reshape(-1).astype(_SYMBOL_DTYPE[h.symbol_size])
+        out = sym.view(np.uint8)[: h.orig_bytes]
+    tracing.count("lzss.decompress.bytes", out.nbytes)
+    tracing.count("lzss.decompress.h2d_bytes", sum(a.nbytes for a in args))
+    tracing.count("lzss.decompress.d2h_bytes", host.nbytes)
+    return out
 
 
 def compression_ratio(data, config: LZSSConfig = DEFAULT_CONFIG) -> float:
@@ -253,11 +287,13 @@ def compress_many(
         # is not padded the way a (1, cap) u8 array's tiles are on TPU (4x),
         # which matters for a checkpoint leaf of a GiB
         data, total = compress_chunks(
-            _pack_padded(raws[0], nc, config), config, jnp.int32(sizes[0])
+            jnp.asarray(_pack_padded(raws[0], nc, config)), config,
+            jnp.int32(sizes[0]),
         )
         data, totals = data[None], total[None]
     else:
-        symbols = jnp.stack([_pack_padded(r, nc, config) for r in raws])
+        symbols = jnp.stack(
+            [jnp.asarray(_pack_padded(r, nc, config)) for r in raws])
         data, totals = compress_many_chunks(
             symbols, config, jnp.asarray(sizes, jnp.int32)
         )
