@@ -154,12 +154,15 @@ def block_vmem_bytes(
     The compressor's section rebuild dominates: some sixteen payload-width
     (g, C*S) int32 intermediates of the byte-space search live at once, next
     to ~24 C-width rows of matching, walk and prefix-sum state; the decoder
-    needs less.  Calibrated on compiles for TPU v5e: (C, g, S) = (2048, 32,
-    4) and (4096, 16, 4) fit ``VMEM_LIMIT_BYTES``, (4096, 32, 4) does not
-    (tests/test_tpu_compile.py compiles the largest default-C candidate).
+    needs less.  The selection walk adds two transposed (C, g) int32
+    scratches whose g lanes pad to whole 128-lane tiles.  Calibrated on
+    compiles for TPU v5e: (C, g, S) = (2048, 32, 4) and (4096, 16, 4) fit
+    ``VMEM_LIMIT_BYTES``, (4096, 32, 4) does not (tests/test_tpu_compile.py
+    compiles the largest candidate of each).
     """
     g, c, s = chunks_per_block, chunk_symbols, symbol_size
-    return 4 * g * c * (24 + 16 * s)
+    walk = 2 * 4 * c * -(-g // 128) * 128
+    return 4 * g * c * (24 + 16 * s) + walk
 
 
 def validate_block_geometry(

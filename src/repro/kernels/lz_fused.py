@@ -9,8 +9,10 @@ of Sitaridi et al., *Massively-Parallel Lossless Data Decompression*).  This
 module folds the whole compressor into one kernel:
 
   * **Kernel I** per chunk block: multi-byte matching, the selection walk
-    and the local prefix sums (shared helpers ``_match_values`` /
-    ``_select_and_scan`` from lz_match.py) — intermediates never leave VMEM.
+    (on the block transposed once, so each serial step is one compare and
+    one select on a row of chunks) and the local prefix sums (shared
+    helpers ``_match_values`` / ``_select_and_scan`` from lz_match.py) —
+    intermediates never leave VMEM.
   * **Kernel II** as a running carry: TPU grid steps execute sequentially,
     so BOTH global exclusive prefix sums degenerate to two SMEM scalars
     accumulated across blocks (the single-pass analogue of CUB's decoupled
@@ -94,8 +96,6 @@ def _mono_kernel(
     ntok_ref,
     psz_ref,
     tot_ref,
-    len_s,
-    emit_s,
     fbuf,
     pbuf,
     fwin,
@@ -130,9 +130,8 @@ def _mono_kernel(
         lengths, offsets = _match_values(
             sym_ref[...], window=window, max_len=max_len
         )
-        len_s[...] = lengths
         emitted, um, _, local_off, psz, ntok = _select_and_scan(
-            len_s, emit_s, lengths, min_match=min_match, symbol_size=s
+            lengths, min_match=min_match, symbol_size=s
         )
         ntok_ref[...] = ntok
         psz_ref[...] = psz
@@ -342,8 +341,6 @@ def _fused_mono(
             pl.BlockSpec((1, 128), lambda i, bt_: (0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, c), jnp.int32),  # lengths (walk source)
-            pltpu.VMEM((g, c), jnp.int32),  # emitted (walk output)
             pltpu.VMEM((g, fl), jnp.int32),  # block flag bytes
             pltpu.VMEM((g, bufp), jnp.int32),  # block payload bytes
             pltpu.VMEM((1, fl + LANES), jnp.int32),  # flag write window

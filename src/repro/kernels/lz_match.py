@@ -8,9 +8,10 @@ TPU mapping of the paper's fused kernel (§3.3.2):
                                          capped log-doubling run lengths
   chunk per thread block             ->  ``chunks_per_block`` chunks stacked on
                                          sublanes (fills the 8x128 VREG tile)
-  one encode thread per block        ->  in-kernel selection walk over lanes
-                                         (aligned 128-lane blocks, one-hot
-                                         lane reductions per position)
+  one encode thread per block        ->  in-kernel selection walk on the
+                                         block transposed once (positions on
+                                         sublanes, chunks on lanes): one
+                                         compare + one select per position
   shared-mem local prefix sum        ->  in-VMEM log-doubling prefix sum
 
 Everything between the symbol load and the (len/off/emitted/local-offset)
@@ -23,7 +24,8 @@ Kernels are validated in interpret mode against kernels/ref.py (pure jnp)
 and compiled for TPU v5e at real sizes by tests/test_tpu_compile.py.  Only
 what Mosaic lowers is used: lane rotates (``pltpu.roll``), gathers within
 one vreg (``lane_gather`` walks wider rows block by block), lane slices at
-128-aligned offsets, and (g, 1) column blocks for per-chunk tables.
+128-aligned offsets, 2-D transposes, sublane slices at 8-aligned offsets,
+and (g, 1) column blocks for per-chunk tables.
 """
 
 from __future__ import annotations
@@ -206,53 +208,64 @@ def _match_kernel(x_ref, len_ref, off_ref, *, window, max_len):
     off_ref[...] = offsets
 
 
-def _select_and_scan(len_ref, emit_ref, lengths, *, min_match, symbol_size):
+def _walk(target):
+    """Greedy token walk over (g, C) landing positions -> (g, C) emitted mask.
+
+    Position j is emitted when the walk lands on it, and the walk then
+    jumps to ``target[:, j]``.  The serial chain is one compare and one
+    select per position on a (1, g) row: the block is transposed once so
+    that positions lie on sublanes and chunks on lanes, each run of 8
+    positions is one aligned (8, g) tile load, and the emitted rows are
+    stored a tile at a time, off the chain.  C must be a multiple of 8, as
+    ``LZSSConfig`` and the container format require.
+    """
+    g, c = target.shape
+    if c % 8:
+        raise ValueError(f"chunk size must be a multiple of 8: {c}")
+
+    def run(tgt, emt):
+        tgt[...] = target.T
+
+        def tile(t, nxt):
+            base = pl.multiple_of(t * 8, 8)
+            rows = tgt[pl.ds(base, 8), :]
+            here = []
+            for k in range(8):
+                h = nxt == base + k
+                nxt = jnp.where(h, rows[k : k + 1, :], nxt)
+                here.append(h.astype(jnp.int32))
+            emt[pl.ds(base, 8), :] = jnp.concatenate(here, axis=0)
+            return nxt
+
+        lax.fori_loop(0, c // 8, tile, jnp.zeros((1, g), jnp.int32))
+        return emt[...].T == 1
+
+    return pl.run_scoped(
+        run, pltpu.VMEM((c, g), jnp.int32), pltpu.VMEM((c, g), jnp.int32)
+    )
+
+
+def _select_and_scan(lengths, *, min_match, symbol_size):
     """Selection walk + local prefix sums, VMEM-resident.
 
-    ``len_ref`` must already hold ``lengths``; ``emit_ref`` is the scratch
-    the walk's dynamic-column stores go through and holds the 0/1 emitted
-    mask on return.  Returns ``(emitted, use_match, sizes, local_off,
-    payload_sizes, n_tokens)`` values — the per-position arrays are (g, C),
-    the per-chunk reductions (g, 1).  Shared by the fused Kernel I below and
-    the single-kernel compressor (lz_fused.py).
+    Returns ``(emitted, use_match, sizes, local_off, payload_sizes,
+    n_tokens)`` values — the per-position arrays are (g, C), the per-chunk
+    reductions (g, 1).  Shared by the fused Kernel I below and the
+    single-kernel compressor (lz_fused.py).
     """
-    g, c = len_ref.shape
-    w = LANES if c % LANES == 0 else c
-    lane = lax.broadcasted_iota(jnp.int32, (g, w), 1)
+    g, c = lengths.shape
+    idx = lax.broadcasted_iota(jnp.int32, (g, c), 1)
 
     # --- encode walk (paper: one thread per block; here: all `g` chunks in
-    # lockstep on sublanes).  Mosaic indexes lanes dynamically only at vreg
-    # granularity, so the walk loads one aligned 128-lane block, steps
-    # through its lanes with one-hot lane reductions, and stores the block's
-    # emitted mask back whole.
-    def block(b, next_pos):
-        start = pl.multiple_of(b * w, w)
-        lens = len_ref[:, pl.ds(start, w)]
-
-        def position(j, carry):
-            next_pos, emit = carry
-            hit = lane == j
-            len_j = jnp.sum(jnp.where(hit, lens, 0), axis=1, keepdims=True)
-            here = next_pos == start + j
-            step = jnp.where(len_j >= min_match, len_j, 1)
-            emit = jnp.where(hit & here, 1, emit)
-            return jnp.where(here, start + j + step, next_pos), emit
-
-        next_pos, emit = lax.fori_loop(
-            0, w, position, (next_pos, jnp.zeros((g, w), jnp.int32))
-        )
-        emit_ref[:, pl.ds(start, w)] = emit
-        return next_pos
-
-    lax.fori_loop(0, c // w, block, jnp.zeros((g, 1), jnp.int32))
+    # lockstep on lanes).  The landing position of every position is
+    # computed vectorised first, so the walk itself does no arithmetic.
+    emitted = _walk(idx + jnp.where(lengths >= min_match, lengths, 1))
 
     # --- local prefix sum (paper's up/down-sweep == lane-shift doubling) ---
-    emitted = emit_ref[...] == 1
     use_match = emitted & (lengths >= min_match)
     sizes = jnp.where(emitted, jnp.where(use_match, 2, symbol_size), 0).astype(
         jnp.int32
     )
-    idx = lax.broadcasted_iota(jnp.int32, (g, c), 1)
     incl = sizes
     ntok = emitted.astype(jnp.int32)
     k = 1
@@ -280,9 +293,10 @@ def _fused_kernel(
     lengths, offsets = _match_values(x_ref[...], window=window, max_len=max_len)
     len_ref[...] = lengths
     off_ref[...] = offsets
-    _, _, _, local_off, paysz, ntok = _select_and_scan(
-        len_ref, emit_ref, lengths, min_match=min_match, symbol_size=symbol_size
+    emitted, _, _, local_off, paysz, ntok = _select_and_scan(
+        lengths, min_match=min_match, symbol_size=symbol_size
     )
+    emit_ref[...] = emitted.astype(jnp.int32)
     lo_ref[...] = local_off               # exclusive local offsets
     paysz_ref[...] = paysz                # per-chunk compressed payload bytes
     ntok_ref[...] = ntok                  # per-chunk token count (flag bits)

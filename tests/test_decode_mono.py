@@ -420,6 +420,19 @@ def test_autotune_cache_hit_revalidates_vmem_fit(tuned_env):
     assert calls["n"] == len(autotune.candidates(key))
 
 
+def test_block_vmem_bytes_counts_the_walk_scratch():
+    """The selection walk's two transposed (C, g) int32 scratches pad g to
+    128 lanes: the bound holds them on top of the rebuild's rows, the
+    calibrated geometries still fit, and (4096, 32, 4) still does not."""
+    for c, g in [(2048, 8), (2048, 32), (4096, 16)]:
+        walk = 2 * 4 * c * 128
+        rebuild = 4 * g * c * (24 + 16 * 4)
+        assert autotune.block_vmem_bytes(c, g, 4) >= rebuild + walk
+        autotune.validate_block_geometry(c, g, 4)
+    with pytest.raises(ValueError, match="4096"):
+        autotune.validate_block_geometry(4096, 32, 4)
+
+
 def test_autotune_cache_hit_revalidates_fixed_c(tuned_env):
     """An entry whose chunk_symbols disagrees with a fixed-C key (stale or
     corrupted cache) must be ignored — the call site's shapes are already

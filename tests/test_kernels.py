@@ -57,6 +57,55 @@ def test_fused_kernel1_sweep(s, mm, c):
         )
 
 
+def _walk_pattern(pattern, nc, c, s, seed):
+    """(nc, C) symbols that steer the selection walk through one edge case.
+
+    ``unique`` is distinct within any 256 symbols (no match at W=255);
+    the other patterns plant copies into it or replace it."""
+    rng = np.random.default_rng(seed)
+    j = np.arange(c)
+    x = np.stack([(j + 37 * r) % 256 for r in range(nc)])
+    mm = max(1, 2 // s + 1)
+    if pattern == "max_run":
+        # a 255-symbol block repeated: length-255 matches at offset 255
+        # that cross several 128-lane blocks and the chunk end
+        block = rng.integers(0, 1 << min(8 * s, 16), size=(nc, 255))
+        x = np.tile(block, -(-c // 255) + 1)[:, :c]
+    elif pattern == "block_edge":
+        # matches of 10 that end exactly on each 128-lane block and the chunk
+        # end, so the walk lands on the block's first position
+        for t in list(range(128, c, 128)) + [c]:
+            if t >= 26:
+                x[:, t - 10 : t] = x[:, t - 26 : t - 16]
+    elif pattern == "min_match_edge":
+        # copies of exactly min_match symbols and of one fewer, at offset 5
+        for i, p in enumerate(range(12, c - 8, 9)):
+            n = mm if i % 2 else mm - 1
+            x[:, p : p + n] = x[:, p - 5 : p - 5 + n]
+    return jnp.asarray(x.astype(np.int32))
+
+
+@pytest.mark.parametrize("s", [1, 2, 4])
+@pytest.mark.parametrize(
+    "pattern", ["unique", "max_run", "block_edge", "min_match_edge"]
+)
+@pytest.mark.parametrize("g", [1, 8])
+@pytest.mark.parametrize("c", [64, 200, 2048])
+def test_selection_walk_matches_reference(c, g, pattern, s):
+    """The transposed walk emits exactly the reference's tokens."""
+    mm = max(1, 2 // s + 1)
+    syms = _walk_pattern(pattern, g + 1, c, s, c + g)
+    got = kmod.lz_kernel1_pallas(
+        syms, window=255, min_match=mm, symbol_size=s,
+        chunks_per_block=g, interpret=True,
+    )
+    exp = ref.lz_kernel1(syms, window=255, min_match=mm, symbol_size=s)
+    for k in exp:
+        np.testing.assert_array_equal(
+            np.asarray(got[k]), np.asarray(exp[k]), err_msg=f"field {k}"
+        )
+
+
 def test_kernel_symbol_dtypes():
     """Symbols packed from u8/u16/u32 views (incl. negative int32 patterns)."""
     rng = np.random.default_rng(0)

@@ -132,6 +132,18 @@ def test_largest_autotune_candidate_compiles(one_chip, direction):
     _compile(fn, one_chip, *shapes)
 
 
+def test_largest_chunk_candidate_compiles(one_chip):
+    """The largest C the autotuner admits, at its largest g, compresses:
+    the selection walk's two transposed (C, g) scratches grow with C, so
+    (4096, 16) carries the most of them next to the rebuild's rows."""
+    s = 4
+    c = max(autotune.CHUNK_SYMBOL_CANDIDATES)
+    key = autotune.TuneKey("TPU_v5_lite", "u32", s, W, "compress", c)
+    c, g = max(autotune.candidates(key), key=lambda cg: cg[1])
+    fn, *shapes = _compress(s, c, g)
+    _compile(fn, one_chip, *shapes)
+
+
 @pytest.mark.parametrize("direction", ["compress", "decompress"])
 def test_batched_codec_compiles(one_chip, direction):
     """compress_many, the KV tier and the gradient exchange vmap the
