@@ -18,9 +18,7 @@ import sys
 import tempfile
 import time
 
-import numpy as np
-
-from bench import codec, manifest, reference
+from bench import codec, manifest
 
 DIRECTIONS = ("compress", "decompress")
 
@@ -125,65 +123,39 @@ def drive(placement, n_fields: int, field_bytes: int, seconds: float,
 
 
 def check(placement, kept: dict, originals, cfg: dict, spec: dict,
-          seed: int) -> dict:
-    """Compare what the window produced with the reference.
+          seed: int, root=manifest.ROOT) -> dict:
+    """Compare what the window produced with the reference, by the check the
+    cell names (``bench/checks/<spec["check"]>.py``).
 
-    ``originals(k)`` gives field ``k``'s bytes as the benchmark made them.
+    ``originals(k)`` gives field ``k``'s bytes as the benchmark made them;
+    ``spec`` is the cell's ``bench/workloads/<cell>.json``.
     Returns ``{number: {"value", "limit", "compared"}}``.
     """
-    s, c, win = cfg["symbol_size"], cfg["chunk_symbols"], cfg["window"]
-    rng = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 0xC4EC])
-    n_fields = int(cfg["fields_per_step"])
-    rt = rt_n = ct = ct_n = 0
-    for k in range(n_fields):
-        want = originals(k)
-        if k not in kept:  # never produced: every byte is missing
-            rt += want.size
-            rt_n += want.size
-            ct += want.size
-            ct_n += want.size
-            continue
-        out, dec = kept[k]
-        rt += reference.roundtrip_mismatch(placement.decoded_bytes(dec), want)
-        rt_n += want.size
-        nc = -(-(want.size // s) // c)
-        chunks = reference.sample_chunks(
-            rng, nc, int(spec["reference_chunks_per_field"]))
-        bad, n = reference.container_mismatch(
-            placement.container_bytes(out), want, chunks, symbol_size=s,
-            window=win, chunk_symbols=c,
-        )
-        ct += bad
-        ct_n += n
-    limits = spec["limits"]
-    return {
-        "roundtrip_mismatch_bytes": dict(
-            value=rt, limit=limits["roundtrip_mismatch_bytes"], compared=rt_n),
-        "container_mismatch_bytes": dict(
-            value=ct, limit=limits["container_mismatch_bytes"], compared=ct_n),
-    }
+    return manifest.module("checks", spec["check"], root).compare(
+        placement, kept, originals, cfg, spec, seed)
 
 
 def passed(checks: dict) -> bool:
     return all(v["value"] <= v["limit"] for v in checks.values())
 
 
-def reader(name: str):
+def reader(name: str, root=manifest.ROOT):
     """The ``read(ctx)`` of ``bench/metrics/<name>.py``."""
-    return manifest.module("metrics", name).read
+    return manifest.module("metrics", name, root).read
 
 
-def time_step(cfg: dict, seed: int) -> tuple:
+def time_step(cfg: dict, seed: int, root=manifest.ROOT) -> tuple:
     """The configuration's fields for ``seed``, from the generator it names
     (``bench/generators/<generator>.py``)."""
-    return manifest.module("generators", cfg["generator"]).time_step(cfg, seed)
+    return manifest.module("generators", cfg["generator"], root).time_step(
+        cfg, seed)
 
 
-def place(traffic: dict, cfg: dict, fields: tuple):
+def place(traffic: dict, cfg: dict, fields: tuple, root=manifest.ROOT):
     """The placement a traffic mix names (``bench/placements/<name>.py``),
     around ``fields``."""
-    return manifest.module("placements", traffic["placement"]).Placement(
-        cfg, fields)
+    return manifest.module("placements", traffic["placement"], root
+                           ).Placement(cfg, fields)
 
 
 def control(cfg: dict, fields: tuple):
@@ -205,16 +177,19 @@ class Readings:
 
 def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
              t_start: float | None = None, require_chip: bool = True,
-             config_overrides: dict | None = None, trace_copy=None) -> dict:
+             config_overrides: dict | None = None, trace_copy=None,
+             root=manifest.ROOT) -> dict:
     """One run of ``cell``; returns the result object (the last line).
 
     ``trace_copy``, a path, keeps a copy of the traced run's ``.xplane.pb``.
+    ``root`` is the tree whose ``BENCHMARK.json`` and ``bench/`` files the
+    run reads.
     """
     t_start = time.perf_counter() if t_start is None else t_start
-    m = manifest.load()
-    files = manifest.cell_files(m, cell)
+    m = manifest.load(root)
+    files = manifest.cell_files(m, cell, root)
     cfg = dict(files["config"], **(config_overrides or {}))
-    readers = {x["name"]: reader(x["name"]) for x in files["per_layer"]}
+    readers = {x["name"]: reader(x["name"], root) for x in files["per_layer"]}
     os.environ["REPRO_AUTOTUNE"] = "0"  # the fixed geometry, no sweep
 
     import jax
@@ -227,10 +202,10 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
 
     n_fields = int(cfg["fields_per_step"])
     fbytes = codec.field_bytes(cfg)
-    fields = time_step(cfg, seed)
+    fields = time_step(cfg, seed, root)
     jax.block_until_ready(fields)
     phases["data"] = time.perf_counter() - t_start
-    placement = place(files["traffic"], cfg, fields)
+    placement = place(files["traffic"], cfg, fields, root)
     del fields
     phases["placement"] = time.perf_counter() - t_start
     info = dict(
@@ -267,7 +242,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
 
     totals = {k: placement.total_bytes(out) for k, (out, _) in w.kept.items()}
     checks = check(placement, w.kept, placement.original_bytes, cfg,
-                   files["check"], seed)
+                   files["check"], seed, root)
     placement.release()
     w.kept.clear()
     correct = w.failed == 0 and passed(checks)

@@ -169,7 +169,7 @@ _MODULES: dict = {}
 def module(kind: str, name: str, root: pathlib.Path = ROOT):
     """``bench/<kind>/<name>.py``, loaded once and found by name alone: a
     configuration's generator, a traffic mix's placement, a configuration's
-    control or a per-layer metric's reader."""
+    control, a cell's check or a per-layer metric's reader."""
     path = root / "bench" / kind / f"{name}.py"
     if path not in _MODULES:
         if not path.exists():
@@ -183,17 +183,27 @@ def module(kind: str, name: str, root: pathlib.Path = ROOT):
 
 
 def cell_files(m: dict, cell: str, root: pathlib.Path = ROOT) -> dict:
-    """Everything one cell is made of, read from its files."""
+    """Everything one cell is made of, read from its files.
+
+    The cell's ``bench/workloads/<cell>.json`` names its check
+    (``bench/checks/<check>.py``) under ``check``; a file without one is
+    refused, so no cell is held to a guarantee it did not name.
+    """
     cells = {w["name"]: w for w in m["workloads"]}
     if cell not in cells:
         raise KeyError(f"no cell {cell!r}; cells: {sorted(cells)}")
     w = cells[cell]
     config = next(c for c in m["configs"] if c["name"] == w["config"])
+    check_file = root / "bench" / "workloads" / f"{cell}.json"
+    check = _json(check_file)
+    if "check" not in check:
+        raise ValueError(f"{check_file} names no check (bench/checks/<name>.py)")
+    _name(check["check"], f"{check_file} check")
     return dict(
         cell=w,
         config=_json(root / config["file"]),
         traffic=_json(root / "bench" / "traffic" / f"{w['traffic']}.json"),
-        check=_json(root / "bench" / "workloads" / f"{cell}.json"),
+        check=check,
         end_to_end=end_to_end(m, cell),
         per_layer=per_layer(m, cell),
     )

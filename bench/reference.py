@@ -1,8 +1,8 @@
-"""The plain reference that decides ``correct``.
+"""The plain reference of the ``lossless`` check (``bench/checks/lossless.py``).
 
-The guarantee a configuration states is losslessness: every byte read back
-equals the byte written.  Two numbers are compared, each against its limit
-(0: both comparisons are exact):
+That check holds a cell to losslessness: every byte read back equals the
+byte written.  Two numbers are compared, each against its limit (0: both
+comparisons are exact):
 
 - ``roundtrip_mismatch_bytes``: bytes where what the timed decoder returned
   differs from the field the benchmark made, over the last round trip of

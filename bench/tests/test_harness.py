@@ -168,6 +168,7 @@ def test_benchmark_files_alone_exit_nonzero(tmp_path):
     lambda: harness.place(dict(placement="nope"), {}, ()),
     lambda: harness.time_step(dict(generator="nope"), 1),
     lambda: harness.reader("nope"),
+    lambda: harness.check(None, {}, None, {}, dict(check="nope"), 1),
 ])
 def test_a_name_with_no_file_is_refused(find):
     with pytest.raises(FileNotFoundError, match="nope.py"):
@@ -194,3 +195,87 @@ def test_result_line_is_json_with_the_contract_keys():
     r = run("quant-u16.resident")
     line = json.loads(json.dumps(r))
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
+
+
+PROBE_CHECK = """
+import pathlib
+
+from bench import manifest
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+
+
+def compare(placement, kept, originals, cfg, spec, seed):
+    checks = manifest.module("checks", "lossless", ROOT).compare(
+        placement, kept, originals, cfg, spec, seed)
+    n = int(cfg["fields_per_step"])
+    checks["fields_not_produced"] = dict(
+        value=n - len(kept), limit=spec["limits"]["fields_not_produced"],
+        compared=n)
+    return checks
+"""
+
+
+def _files(root):
+    return {p.relative_to(root): p.read_bytes()
+            for p in (root / "bench").rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+def test_a_cell_with_a_check_of_its_own_is_added_as_new_files(tmp_path):
+    """The route a cell with another guarantee takes: a config, a cell file
+    and a check, all new, and entries appended to ``BENCHMARK.json``."""
+    shutil.copy(manifest.ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(manifest.BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _files(manifest.ROOT)
+    bench = tmp_path / "bench"
+    cfg = json.loads((bench / "configs" / "hurr-field-f32.json").read_text())
+    cfg["name"] = "hurr-field-f32-probe"
+    (bench / "configs" / "hurr-field-f32-probe.json").write_text(
+        json.dumps(cfg))
+    cell = "field-f32-probe.resident"
+    spec = json.loads(
+        (bench / "workloads" / "field-f32.resident.json").read_text())
+    spec["check"] = "probe"
+    spec["limits"]["fields_not_produced"] = 0
+    (bench / "workloads" / f"{cell}.json").write_text(json.dumps(spec))
+    (bench / "checks" / "probe.py").write_text(PROBE_CHECK)
+    m = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    original = json.loads(json.dumps(m))
+    m["configs"].append(dict(
+        next(c for c in m["configs"] if c["name"] == "hurr-field-f32"),
+        name=cfg["name"], file="bench/configs/hurr-field-f32-probe.json"))
+    m["workloads"].append(dict(
+        next(w for w in m["workloads"] if w["name"] == "field-f32.resident"),
+        name=cell, config=cfg["name"]))
+    for x in m["end_to_end"] + m["per_layer"]:
+        if "field-f32.resident" in x.get("workloads", []):
+            x["workloads"].append(cell)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(m))
+
+    r = harness.run_cell(cell, SEED, 0.2, False, require_chip=False,
+                         config_overrides=TINY, root=tmp_path)
+    assert r["correct"] and r["failed"] == 0
+    assert r["checks"]["fields_not_produced"] == {"value": 0, "limit": 0}
+    assert set(r["checks"]) == {"roundtrip_mismatch_bytes",
+                                "container_mismatch_bytes",
+                                "fields_not_produced"}
+    assert set(r["metrics"]) == {"compress_GBps", "decompress_GBps",
+                                 "ratio", "setup_s"}
+
+    after = _files(tmp_path)
+    assert {k: v for k, v in after.items() if k in before} == before
+    assert set(after) - set(before) == {
+        pathlib.Path("bench/configs/hurr-field-f32-probe.json"),
+        pathlib.Path(f"bench/workloads/{cell}.json"),
+        pathlib.Path("bench/checks/probe.py")}
+    # BENCHMARK.json changed only by what was appended
+    for section in ("configs", "workloads"):
+        assert m[section][:-1] == original[section]
+    for x, y in zip(m["end_to_end"] + m["per_layer"],
+                    original["end_to_end"] + original["per_layer"]):
+        if "workloads" in y:
+            assert x["workloads"][:len(y["workloads"])] == y["workloads"]
+        assert {k: v for k, v in x.items() if k != "workloads"} == \
+            {k: v for k, v in y.items() if k != "workloads"}

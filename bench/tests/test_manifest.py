@@ -2,6 +2,7 @@
 
 import copy
 import json
+import shutil
 
 import pytest
 
@@ -31,6 +32,8 @@ def test_committed_manifest_validates_and_resolves(m):
                                ).Placement
         assert callable(manifest.module("controls", cfg["control"]["kind"])
                         .make)
+        assert callable(manifest.module("checks", files["check"]["check"])
+                        .compare)
     for c in m["configs"]:
         cfg = json.loads((manifest.ROOT / c["file"]).read_text())
         assert cfg["name"] == c["name"]
@@ -85,3 +88,17 @@ def test_a_module_is_found_by_its_file_name_alone(tmp_path):
     assert mod.time_step({}, 7) == (7,)
     with pytest.raises(FileNotFoundError):
         manifest.module("generators", "absent", root=tmp_path)
+
+
+def test_a_cell_file_that_names_no_check_is_refused(tmp_path):
+    shutil.copy(manifest.ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(manifest.BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    path = tmp_path / "bench" / "workloads" / "quant-u16.resident.json"
+    spec = json.loads(path.read_text())
+    del spec["check"]
+    path.write_text(json.dumps(spec))
+    m = manifest.load(tmp_path)
+    with pytest.raises(ValueError, match="quant-u16.resident.json"):
+        manifest.cell_files(m, "quant-u16.resident", root=tmp_path)
+    manifest.cell_files(m, "field-f32.resident", root=tmp_path)
